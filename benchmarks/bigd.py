@@ -14,7 +14,7 @@ The PR-7 acceptance story at d in the thousands, on one page:
   (d_tile=128, n_chunk=1024 — what the engine would pick blind) by
   >= 1.2x on at least one (path, shape) point.
 
-CPU runs the xla backend (pallas interprets on CPU); TPU/GPU run the
+CPU runs the xla backend (pallas interprets on CPU); TPU runs the
 kernels natively. --quick drops the d=4096 timing rows but keeps the
 analytic budget checks, which are platform-independent.
 """
@@ -68,7 +68,7 @@ def _path_fn(eng: GramEngine, path: str, xf, xi, packed, n: int):
 
 
 def run(quick: bool = False) -> dict:
-    on_accel = jax.default_backend() in ("tpu", "gpu")
+    on_accel = jax.default_backend() == "tpu"
     backend = "pallas" if on_accel else "xla"
     base = GramEngine(backend=backend)
     mono = _engine_with(base, GramConfig())

@@ -16,7 +16,7 @@ The paper-claim check (also the PR acceptance bar): the packed path moves
 32x fewer — 4 bytes/symbol vs 1 bit/symbol.)
 
 Timing on CPU runs the xla backend (the pallas kernels interpret on CPU,
-which benchmarks the interpreter, not the kernel); on TPU/GPU it times the
+which benchmarks the interpreter, not the kernel); on TPU it times the
 pallas kernels natively. The acceptance shape's bytes row is always
 emitted, even under --quick / when timing at that size is skipped.
 """
@@ -63,7 +63,7 @@ def _operands(n, d, seed=0):
 
 
 def run(quick: bool = False) -> dict:
-    on_accel = jax.default_backend() in ("tpu", "gpu")
+    on_accel = jax.default_backend() == "tpu"
     backend = "pallas" if on_accel else "xla"
     eng = GramEngine(backend=backend)
     shapes = [(8192, 256)] if quick else [(16384, 512), ACCEPTANCE_SHAPE]

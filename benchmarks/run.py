@@ -101,11 +101,10 @@ def write_bench_bigd(payload: dict, path: str = BENCH_BIGD_JSON) -> str:
 
 def write_bench_roofline(payload: dict, path: str = BENCH_ROOFLINE_JSON) -> str:
     """Persist the distributed-GGM roofline artifact: per-(placement, shape)
-    measured step time vs the analytic collective/compute/HBM bounds, the
-    roofline fraction against the binding term, and the model-sanity checks
-    (no hard fraction gate on CPU hosts — see ggm_roofline.py)."""
+    analytic collective/compute/HBM bounds from the AOT-lowered program,
+    the binding term, and the model-sanity checks (see ggm_roofline.py)."""
     return _write_slim(payload, (
-        "platform", "d", "n", "rows", "thresholds", "checks"), path)
+        "platform", "d", "n", "rows", "checks"), path)
 
 
 def write_bench_serve(payload: dict, path: str = BENCH_SERVE_JSON) -> str:
@@ -158,7 +157,19 @@ def write_bench_gram(payload: dict, path: str = BENCH_GRAM_JSON) -> str:
     return path
 
 
+def use_compile_cache() -> None:
+    """Keep XLA's persistent compile cache at one fixed path in the
+    checkout, unless ``JAX_COMPILATION_CACHE_DIR`` already names one (the
+    path is part of the cache key, so it must not move between runs)."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_REPO_ROOT, ".jax_cache"))
+
+
 def main() -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default="")

@@ -158,7 +158,13 @@ def _crash(quick: bool, workdir: str) -> dict:
 
     crash_dir = os.path.join(workdir, "crash")
     crash_after = 30 if quick else 60
-    env = dict(os.environ, PYTHONPATH=_SRC)
+    # The child is an explicit CPU process: this process may hold the
+    # chip, and a second process cannot open it. Bit-identity still holds
+    # across the two platforms: the child only journals and folds sign
+    # payloads, whose Grams are exact integers on every backend and sum in
+    # float64 on the host, and the final force_resolve re-solves every
+    # tenant here, so the compared structures come from one device.
+    env = dict(os.environ, PYTHONPATH=_SRC, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "-c",
          _CHILD.format(tcfg=tcfg, scfg=scfg, crash=crash_after), crash_dir],

@@ -1,4 +1,1 @@
 """repro: distributed tree-GGM structure learning + multi-pod JAX framework."""
-from . import _jaxcompat
-
-_jaxcompat.ensure()

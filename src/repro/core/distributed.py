@@ -39,7 +39,7 @@ the SAME stages over the Monte-Carlo trial plane — trials sharded over
 telemetry and bit-identical metrics to the single-device engine.
 
 Every Gram goes through :class:`repro.core.gram.GramEngine` (Pallas kernels
-on TPU/GPU, XLA matmuls on CPU). For ``wire="packed"`` with the sign method
+on TPU, XLA matmuls on CPU). For ``wire="packed"`` with the sign method
 the Gram is computed **directly on the packed payload** via XNOR+popcount
 (G = n - 2*popcount(xor)) — the gathered wire bytes are the kernel operand,
 nothing is unpacked back to int8/f32. For int8 wires, codes enter the kernel

@@ -4,7 +4,7 @@ All estimators take the full received code matrix U of shape (n, d) and
 produce pairwise (d, d) statistic matrices; they are pure and jit-able.
 The pairwise contraction U^T U is the compute hot spot: every estimator
 routes it through :class:`repro.core.gram.GramEngine` (Pallas kernels on
-TPU/GPU, plain XLA matmuls on CPU, numpy host reference), so the same code
+TPU, plain XLA matmuls on CPU, numpy host reference), so the same code
 serves as both the production path and the kernels' reference semantics.
 Pass ``engine=`` to pin a backend; ``None`` uses the process default.
 

@@ -39,6 +39,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+#: f32 matmuls run at full precision: the TPU's default rounds operands
+#: to bf16
+_HI = jax.lax.Precision.HIGHEST
+
 #: default ISTA iteration budget shared by every glasso entry point (the
 #: trial plane, the wire runtime and the host helpers key their jit caches
 #: on it, so one number keeps them on one compiled solver).
@@ -73,7 +77,7 @@ def nearest_correlation(S: jax.Array, *, eps: float = 1e-4) -> jax.Array:
     S = (S + jnp.swapaxes(S, -1, -2)) / 2.0
     w, v = jnp.linalg.eigh(S)
     w = jnp.maximum(w, eps)
-    S = jnp.einsum("...ij,...j,...kj->...ik", v, w, v)
+    S = jnp.einsum("...ij,...j,...kj->...ik", v, w, v, precision=_HI)
     dinv = 1.0 / jnp.sqrt(jnp.diagonal(S, axis1=-2, axis2=-1))
     S = S * dinv[..., :, None] * dinv[..., None, :]
     return (S + jnp.swapaxes(S, -1, -2)) / 2.0
@@ -103,7 +107,7 @@ def _carry_init(S: jax.Array, lam: jax.Array, step_scale: float, eps: float):
     off = ~jnp.eye(d, dtype=bool)
     ws, v0 = jnp.linalg.eigh(S + 0.5 * jnp.eye(d))
     w0 = jnp.maximum(1.0 / jnp.maximum(ws, eps), eps)
-    theta0 = (v0 * w0) @ v0.T
+    theta0 = jnp.matmul(v0 * w0, v0.T, precision=_HI)
     eta0 = step_scale * (1.0 / jnp.linalg.norm(S + jnp.eye(d), 2)) ** 2
     obj0 = _objective(w0, theta0, S, lam, off)
     return theta0, w0, v0, eta0, obj0
@@ -146,14 +150,14 @@ def _glasso_run(
 
     def body(carry):
         theta, w, v, eta, obj, it, done = carry
-        g = S - (v / w) @ v.T
+        g = S - jnp.matmul(v / w, v.T, precision=_HI)
         z = theta - eta * g
         z = jnp.where(off, soft_threshold(z, eta * lam), z)
         z = (z + z.T) / 2.0
         # PSD projection with an eigenvalue floor (keeps logdet finite)
         wz, vz = jnp.linalg.eigh(z)
         wz = jnp.maximum(wz, eps)
-        z = (vz * wz) @ vz.T
+        z = jnp.matmul(vz * wz, vz.T, precision=_HI)
         obj_z = _objective(wz, z, S, lam, off)
         # monotone guard: a candidate that increases the objective means
         # the step overshot the local curvature — reject it and halve eta

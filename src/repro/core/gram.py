@@ -16,8 +16,10 @@ routes all of them through a single engine with three backends:
 * ``numpy``   — host-side reference (returns ``np.ndarray``), used by tests
   and the host-Kruskal path; exact integer arithmetic for sign codes.
 
-``backend="auto"`` (the default engine) resolves to ``pallas`` on TPU/GPU
-and ``xla`` on CPU, overridable with the ``REPRO_GRAM_BACKEND`` env var.
+``backend="auto"`` (the default engine) resolves to ``pallas`` on TPU and
+``xla`` on CPU, overridable with the ``REPRO_GRAM_BACKEND`` env var. Float
+contractions on the xla backend run at ``Precision.HIGHEST``: the TPU's
+default f32 matmul rounds its operands to bf16.
 
 Three input kinds cover every wire format; HBM/wire bytes per symbol:
 
@@ -67,8 +69,10 @@ orthogonal engine knobs bound them:
 (backend, path, shape-bucket, platform) by timing the candidate set in
 :func:`candidate_configs` on first use. Winners persist to a JSON cache
 (``REPRO_GRAM_AUTOTUNE_CACHE``, default ``~/.cache/repro/gram_autotune.json``,
-keyed by platform so one file serves heterogeneous fleets); warm processes
-skip the sweep. ``REPRO_GRAM_AUTOTUNE=0`` disables sweeping entirely.
+keyed by platform and device kind so one file serves heterogeneous fleets);
+warm processes skip the sweep. A candidate that fails to compile is skipped
+with a message on stderr; a sweep in which every candidate fails raises.
+``REPRO_GRAM_AUTOTUNE=0`` disables sweeping entirely.
 Sweeps only ever run eagerly: inside a jit trace the engine falls back to
 the cached winner or the engine's own config — pre-tune with
 :meth:`GramEngine.tune` (``run_trials`` does) before tracing hot loops.
@@ -83,6 +87,7 @@ import dataclasses
 import functools
 import json
 import os
+import sys
 import time
 from typing import Literal
 
@@ -114,7 +119,7 @@ class GramConfig:
 
     block_n: int = 512
     block_d: int = 256
-    block_b: int = 128
+    block_b: int = 512
     d_tile: int | None = None
     n_chunk: int | None = None
 
@@ -171,9 +176,17 @@ def _to_f32(a, xp):
 
 
 def _contract_values(uf, vf, batched: bool, xp):
-    if batched:
-        return xp.einsum("bnd,bne->bde", uf, vf)
-    return uf.T @ vf
+    """u^T v over the sample axis. The unbatched form runs as a batch of
+    one through the same contraction, so ``gram(u[i])`` and
+    ``gram_batch(u)[i]`` round identically."""
+    if not batched:
+        return _contract_values(uf[None], vf[None], True, xp)[0]
+    if xp is np:
+        return np.matmul(np.swapaxes(uf, -1, -2), vf)
+    return jax.lax.dot_general(
+        uf, vf, (((1,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _contract_planes(uf, vf, batched: bool):
@@ -189,13 +202,14 @@ class GramEngine:
     Attributes:
       backend: ``auto`` | ``pallas`` | ``xla`` | ``numpy``. ``auto`` resolves
         per-call from ``REPRO_GRAM_BACKEND`` or the default jax backend
-        (pallas on TPU/GPU, xla on CPU).
+        (pallas on TPU, xla elsewhere).
       interpret: Pallas interpret-mode override. ``None`` = interpret iff
-        running on CPU (so ``backend="pallas"`` is always safe in tests).
+        not running on a TPU (so ``backend="pallas"`` is always safe in
+        tests).
       block_n / block_d / block_b: kernel tile sizes for the pallas backend.
         ``block_d`` is clamped to 128 for the code/packed kernels (their
-        per-tile VMEM working sets — one-hot decode and XOR intermediate —
-        scale with block_d^2).
+        per-tile VMEM working sets — decoded f32 tiles and the XOR
+        intermediate — grow with block_d).
       d_tile: stream the (d, d) output in (d_tile, d_tile) blocks when d
         exceeds it (``None`` = monolithic). Bit-identical for integer-exact
         paths; bounds every backend's transient working set.
@@ -215,7 +229,7 @@ class GramEngine:
     interpret: bool | None = None
     block_n: int = 512
     block_d: int = 256
-    block_b: int = 128
+    block_b: int = 512
     d_tile: int | None = None
     n_chunk: int | None = None
     autotune: bool = False
@@ -224,14 +238,14 @@ class GramEngine:
         b = self.backend
         if b == "auto":
             b = os.environ.get("REPRO_GRAM_BACKEND") or (
-                "pallas" if jax.default_backend() in ("tpu", "gpu") else "xla")
+                "pallas" if jax.default_backend() == "tpu" else "xla")
         if b not in ("pallas", "xla", "numpy"):
             raise ValueError(f"unknown gram backend {b!r}")
         return b
 
     def _interpret(self) -> bool:
         if self.interpret is None:
-            return jax.default_backend() == "cpu"
+            return jax.default_backend() != "tpu"
         return self.interpret
 
     def _base_config(self) -> GramConfig:
@@ -401,7 +415,7 @@ class GramEngine:
     @staticmethod
     def _decode_jnp(codes: jax.Array, centroids: jax.Array) -> jax.Array:
         # out-of-range codes (incl. the -1 mask sentinel) decode to 0.0 —
-        # same semantics as the kernel's one-hot decode. The bounds check
+        # same semantics as the kernel's decode. The bounds check
         # must be explicit: take's own OOB modes normalize negatives first.
         cb = jnp.asarray(centroids, dtype=jnp.float32)
         c = jnp.asarray(codes).astype(jnp.int32)
@@ -570,19 +584,23 @@ def gram_working_set_bytes(
 def default_memory_budget() -> int:
     """Per-device memory budget in bytes for plan/tile decisions.
 
-    ``REPRO_MEMORY_BUDGET_BYTES`` overrides; else the backend's reported
-    ``bytes_limit`` (HBM on accelerators); else an 8 GiB host heuristic.
+    ``REPRO_MEMORY_BUDGET_BYTES`` overrides; else the device's reported
+    ``bytes_limit`` (HBM on a TPU). A TPU that reports none is an error —
+    plans and tiles sized from a guessed HBM would be wrong on the chip;
+    host backends, which report none, get an 8 GiB heuristic.
     """
     env = os.environ.get(MEMORY_BUDGET_ENV)
     if env:
         return int(env)
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit") or 0)
-        if limit > 0:
-            return limit
-    except Exception:  # memory_stats is optional per backend
-        pass
+    dev = jax.devices()[0]
+    stats = dev.memory_stats() or {}
+    limit = int(stats.get("bytes_limit") or 0)
+    if limit > 0:
+        return limit
+    if dev.platform == "tpu":
+        raise RuntimeError(
+            f"{dev.device_kind} reports no bytes_limit in memory_stats(); "
+            f"set {MEMORY_BUDGET_ENV} to its HBM size in bytes")
     return 8 << 30
 
 
@@ -633,7 +651,8 @@ def _pow2_bucket(x: int) -> int:
 
 
 def _tune_key(path: str, n: int, d: int, backend: str) -> str:
-    return (f"{jax.default_backend()}:{backend}:{path}"
+    kind = jax.devices()[0].device_kind
+    return (f"{jax.default_backend()}:{kind}:{backend}:{path}"
             f":n{_pow2_bucket(n)}:d{_pow2_bucket(d)}")
 
 
@@ -693,8 +712,8 @@ def candidate_configs(
     cands = [GramConfig()]
     if backend == "pallas":
         if path == "packed":
-            for bd in (64, 128, 256):
-                for bb in (128, 256):
+            for bd in (64, 128):
+                for bb in (512, 1024):
                     cands.append(GramConfig(block_d=bd, block_b=bb))
         elif path == "code":
             for bn in (256, 512, 1024):
@@ -775,13 +794,15 @@ def tuned_config(
     sweep: bool = True,
     budget: int | None = None,
 ) -> GramConfig:
-    """Cached tuned config for (platform, backend, path, shape bucket).
+    """Cached tuned config for (platform, device kind, backend, path,
+    shape bucket).
 
     Resolution order: in-memory cache -> JSON cache file -> (if ``sweep``
     and the ``REPRO_GRAM_AUTOTUNE`` hatch is open) a timing sweep over
     :func:`candidate_configs` at the bucketed shape, persisted for future
     processes. With sweeping unavailable, returns ``default`` (the engine's
-    own config).
+    own config). A candidate that raises is reported on stderr and
+    skipped; if every candidate raises, so does the sweep.
     """
     global _sweep_count
     default = default or engine._base_config()
@@ -801,14 +822,23 @@ def tuned_config(
     nb = min(nb, 4096)  # cap sweep cost; tiles transfer across n buckets
     _sweep_count += 1
     ops = _sweep_operands(path, nb, db, backend)
-    best_cfg, best_t = default, float("inf")
+    best_cfg, best_t = None, float("inf")
+    errors = []
     for cfg in candidate_configs(path, nb, db, backend, budget=budget):
         try:
             t = _time_config(engine, cfg, path, ops, nb)
-        except Exception:
-            continue  # config invalid on this backend/shape: skip
+        except Exception as e:  # noqa: BLE001 — reported, then skipped
+            errors.append(f"{cfg}: {type(e).__name__}: {e}")
+            print(f"gram autotune [{key}] skipped {cfg}: "
+                  f"{type(e).__name__}: {str(e).splitlines()[0][:200]}",
+                  file=sys.stderr, flush=True)
+            continue
         if t < best_t:
             best_cfg, best_t = cfg, t
+    if best_cfg is None:
+        raise RuntimeError(
+            f"gram autotune [{key}]: every candidate failed:\n"
+            + "\n".join(errors))
     _tuned[key] = best_cfg
     _store_cache_file()
     return best_cfg

@@ -35,6 +35,10 @@ import jax.numpy as jnp
 
 from . import trees
 
+#: f32 matmuls run at full precision: the TPU's default rounds operands
+#: to bf16, which would put the samples and solves off their f32 reference
+_HI = jax.lax.Precision.HIGHEST
+
 
 def bfs_order(d: int, edges: list[tuple[int, int]], root: int = 0):
     """Return (order, parent, parent_weight_index): a BFS node ordering with
@@ -78,7 +82,7 @@ def sample_tree_ggm_parents(
     c = jnp.sqrt(jnp.clip(1.0 - jnp.square(rho), 0.0, None)).at[0].set(1.0)
     z = jax.random.normal(key, (n, d), dtype=jnp.float32)
     M = trees.path_product_mixer(parent, rho)
-    return (z * c[None, :]) @ M.T
+    return jnp.matmul(z * c[None, :], M.T, precision=_HI)
 
 
 def sample_tree_ggm_batch(
@@ -152,7 +156,7 @@ def sample_tree_ggm_rows_batch(
     z = _row_normals(keys, n, d)
     c = jnp.sqrt(jnp.clip(1.0 - jnp.square(rhos), 0.0, None)).at[:, 0].set(1.0)
     M = jax.vmap(trees.path_product_mixer)(parents, rhos)
-    return jnp.einsum("tnd,ted->tne", z * c[:, None, :], M)
+    return jnp.einsum("tnd,ted->tne", z * c[:, None, :], M, precision=_HI)
 
 
 def sample_ggm_rows(key: jax.Array, n: int, chol: jax.Array) -> jax.Array:
@@ -178,7 +182,8 @@ def sample_ggm_rows_batch(
     """
     d = chols.shape[-1]
     z = _row_normals(keys, n, d)
-    return jnp.einsum("tnd,ted->tne", z, jnp.asarray(chols, jnp.float32))
+    return jnp.einsum("tnd,ted->tne", z, jnp.asarray(chols, jnp.float32),
+                      precision=_HI)
 
 
 def sample_tree_ggm(
@@ -207,4 +212,5 @@ def sample_ggm(key: jax.Array, n: int, corr: np.ndarray) -> jax.Array:
     d = corr.shape[0]
     chol = np.linalg.cholesky(np.asarray(corr, dtype=np.float64) + 1e-12 * np.eye(d))
     z = jax.random.normal(key, (n, d), dtype=jnp.float32)
-    return z @ jnp.asarray(chol.T, dtype=jnp.float32)
+    return jnp.matmul(z, jnp.asarray(chol.T, dtype=jnp.float32),
+                      precision=_HI)

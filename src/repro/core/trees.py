@@ -23,6 +23,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+#: f32 matmuls run at full precision: the TPU's default rounds operands
+#: to bf16
+_HI = jax.lax.Precision.HIGHEST
+
 
 def random_tree(d: int, rng: np.random.Generator) -> list[tuple[int, int]]:
     """Uniform random labelled tree on ``d`` nodes via a Pruefer sequence."""
@@ -190,8 +194,8 @@ def path_product_mixer(parent: jax.Array, rho: jax.Array) -> jax.Array:
     M = jnp.eye(d, dtype=jnp.float32) + B
     P = B
     for _ in range(max(int(np.ceil(np.log2(max(d, 2)))), 1)):
-        P = P @ P
-        M = M + M @ P
+        P = jnp.matmul(P, P, precision=_HI)
+        M = M + jnp.matmul(M, P, precision=_HI)
     return M
 
 
@@ -204,7 +208,7 @@ def tree_correlation(parent: jax.Array, rho: jax.Array) -> jax.Array:
     rho = jnp.asarray(rho, jnp.float32)
     c = jnp.sqrt(jnp.clip(1.0 - jnp.square(rho), 0.0, None)).at[0].set(1.0)
     A = path_product_mixer(parent, rho) * c[None, :]
-    return A @ A.T
+    return jnp.matmul(A, A.T, precision=_HI)
 
 
 def structure_hamming(adj_a: jax.Array, adj_b: jax.Array) -> jax.Array:

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute in ``interpret=True`` mode; on TPU
-they compile natively. ``INTERPRET`` resolves once at import time from the
-default backend and can be overridden per call.
+On CPU the kernels execute in ``interpret=True`` mode; on TPU they compile
+natively. ``interpret=None`` resolves per call from the default backend,
+so importing this module starts no backend.
 """
 from __future__ import annotations
 
@@ -15,7 +15,11 @@ from .sign_corr import code_corr as _code_corr
 from .sign_corr import sign_corr as _sign_corr
 from .sign_corr import sign_corr_packed as _sign_corr_packed
 
-INTERPRET = jax.default_backend() == "cpu"
+
+def _interpret(interpret: bool | None) -> bool:
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def sign_corr(u, v=None, *, block_n: int = 512, block_d: int = 256,
@@ -24,35 +28,31 @@ def sign_corr(u, v=None, *, block_n: int = 512, block_d: int = 256,
         u, v,
         block_n=block_n,
         block_d=block_d,
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=_interpret(interpret),
     )
 
 
 def code_corr(codes, centroids, codes_rhs=None, *,
               interpret: bool | None = None, **kw):
     return _code_corr(
-        codes, centroids, codes_rhs,
-        interpret=INTERPRET if interpret is None else interpret, **kw)
+        codes, centroids, codes_rhs, interpret=_interpret(interpret), **kw)
 
 
 def sign_corr_packed(packed, n, packed_rhs=None, *,
                      interpret: bool | None = None, **kw):
     return _sign_corr_packed(
-        packed, n, packed_rhs,
-        interpret=INTERPRET if interpret is None else interpret, **kw)
+        packed, n, packed_rhs, interpret=_interpret(interpret), **kw)
 
 
 def quantize_fused(x, rate: int, *, interpret: bool | None = None, **kw):
-    return _quantize_fused(
-        x, rate, interpret=INTERPRET if interpret is None else interpret, **kw
-    )
+    return _quantize_fused(x, rate, interpret=_interpret(interpret), **kw)
 
 
 def decode_attention(q, k, v, pos, *, window=None, interpret: bool | None = None, **kw):
     return _decode_attention(
         q, k, v, pos,
         window=window,
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=_interpret(interpret),
         **kw,
     )
 
@@ -61,4 +61,4 @@ def flash_prefill(q, k, v, *, causal=True, window=0,
                   interpret: bool | None = None, **kw):
     return _flash_prefill(
         q, k, v, causal=causal, window=window,
-        interpret=INTERPRET if interpret is None else interpret, **kw)
+        interpret=_interpret(interpret), **kw)

@@ -15,10 +15,12 @@ bytes/symbol table):
   in-tile instead of materializing an f32 copy of U in HBM, so HBM traffic is
   1 byte/symbol instead of 4.
 * :func:`code_corr` — int8 *bin codes* plus a <=2^R-entry centroid codebook.
-  The codebook lives in VMEM and the centroid decode is a fused one-hot
-  contraction per tile (same idiom as ``kernels.quantize``), so the per-symbol
-  Gram consumes the wire payload directly: 1 byte/symbol of HBM traffic and
-  no decoded f32 (or even centroid-valued int8) copy ever exists in HBM.
+  The codebook lives in SMEM and each tile decodes through a chain of L
+  2-D selects (no (bn, bd, L) one-hot cube), so the per-symbol Gram
+  consumes the wire payload directly: 1 byte/symbol of HBM traffic and no
+  decoded copy ever exists in HBM. The decoded f32 tiles contract at
+  ``Precision.HIGHEST``, so the result carries f32 accumulation error only
+  (no bf16 rounding of the centroids).
 * :func:`sign_corr_packed` — uint8 *bit-packed* sign codes (8 symbols/byte,
   the honest 1-bit wire format of ``quantizers.pack_codes``). Uses the
   XNOR+popcount identity: with u in {-1,+1} encoded as bits b,
@@ -27,14 +29,17 @@ bytes/symbol table):
 
   where zero-padded tail bytes cancel exactly (pad bits XOR to 0). HBM
   traffic is 1 *bit*/symbol — 8x under int8, 32x under f32 — and the wire
-  payload and the compute payload are the same buffer. Popcount is SWAR
-  (shift/mask adds), pure VPU ops.
+  payload and the compute payload are the same buffer. The wrapper views
+  the bytes as int32 words (4 bytes per lane; the TPU vector unit has no
+  8-bit arithmetic) and the kernel runs a SWAR popcount on the words, 8
+  output rows at a time, so its XOR intermediate is (8, bd, bw) int32 —
+  512 KiB at bd = bw = 128.
 
 Block shapes default to (512, 256) for the MXU kernels: per-step VMEM =
 2 * 512*256 B (int8 in) + 2 * 512*256*2 B (bf16 tiles) + 256*256*4 B (acc)
-≈ 1.3 MB, comfortably inside v5e's ~16 MB VMEM; all dims are multiples of
-the 128-lane MXU tiling. The packed kernel defaults to (128, 128) byte
-tiles: its (bd, bd, bb) XOR intermediate is 2 MB at that size.
+≈ 1.3 MB, comfortably inside v5e's ~16 MB VMEM. Output tiles are either
+the whole (padded) d or a multiple of the 128-lane tiling
+(:func:`_d_block`), the two shapes the TPU lowering accepts.
 
 Every kernel is TILED over (d_tile, d_tile) OUTPUT blocks with an n-step
 accumulation loop as the trailing grid dimension, so per-program VMEM is
@@ -64,6 +69,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 #: Output-tile pad candidates for the MXU kernels, shared with the
@@ -76,13 +82,16 @@ def _d_block(d_max: int, block_d: int) -> int:
     """Output-tile edge for a Gram over d_max features.
 
     Returns the smallest :data:`PAD_TILES` candidate >= d_max when one fits
-    under ``block_d`` (so d=20 pads to 32 lanes, not 128); otherwise the
-    legacy 128-lane-multiple clamp.
+    under ``block_d`` (so d=20 pads to 32 lanes, not 128): the tile is then
+    the whole padded output. Otherwise the tile is ``block_d`` rounded up
+    to the 128-lane tiling, capped at d_max rounded the same way — a tile
+    narrower than 128 lanes that does not span the output is not a legal
+    TPU block.
     """
     for tile in PAD_TILES:
         if d_max <= tile <= block_d:
             return tile
-    return min(block_d, _ceil_mult(d_max, 128))
+    return min(_ceil_mult(block_d, 128), _ceil_mult(d_max, 128))
 
 
 def _as_batched(u: jax.Array) -> tuple[jax.Array, bool]:
@@ -170,18 +179,17 @@ def _code_corr_kernel(c_l_ref, c_r_ref, cents_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    cents = cents_ref[...]  # (1, L)
-    levels = jax.lax.broadcasted_iota(jnp.int32, (1, 1, cents.shape[1]), 2)
-
-    def decode(codes):  # one-hot contraction: VPU-friendly, no gather
-        onehot = codes.astype(jnp.int32)[:, :, None] == levels
-        return jnp.sum(
-            jnp.where(onehot, cents[0][None, None, :], 0.0), axis=-1
-        ).astype(jnp.bfloat16)
+    def decode(codes):  # one 2-D select per level; no (bn, bd, L) cube
+        c = codes.astype(jnp.int32)
+        val = jnp.zeros(c.shape, jnp.float32)
+        for level in range(cents_ref.shape[1]):
+            val = jnp.where(c == level, cents_ref[0, level], val)
+        return val
 
     out_ref[0] += jax.lax.dot_general(
         decode(c_l_ref[0]), decode(c_r_ref[0]),
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -200,7 +208,7 @@ def code_corr(
 
     Args:
       codes: (n, d_l) — or batch-stacked (b, n, d_l) — int8 bin indices in
-        [0, L). Negative codes match no one-hot level and decode to 0, so a
+        [0, L). Negative codes match no level and decode to 0, so a
         -1 sentinel masks out padded samples (the trial plane's
         valid-length masking under shape bucketing).
       centroids: (L,) codebook (``PerSymbolQuantizer.centroids``), L <= 128;
@@ -208,13 +216,13 @@ def code_corr(
       codes_rhs: optional (n, d_r) / (b, n, d_r) right operand.
     Returns:
       (d_l, d_r) — batched: (b, d_l, d_r) — float32 Gram of the centroid
-      values; the decoded values only ever exist as bf16 VMEM tiles (never
+      values; the decoded values only ever exist as f32 VMEM tiles (never
       in HBM).
     """
     if codes_rhs is None:
         codes_rhs = codes
     (L,) = centroids.shape
-    assert L <= 128, "codebook must fit a VMEM lane tile (R <= 7)"
+    assert L <= 128, "codebook holds at most 2^7 levels (R <= 7)"
     codes, batched = _as_batched(codes)
     codes_rhs, _ = _as_batched(codes_rhs)
     b, n, dl = codes.shape
@@ -223,7 +231,7 @@ def code_corr(
     bn = min(block_n, _ceil_mult(n, 8))
     bd = _d_block(max(dl, dr), block_d)
     n_p, dl_p, dr_p = _ceil_mult(n, bn), _ceil_mult(dl, bd), _ceil_mult(dr, bd)
-    # pad with -1: it matches no one-hot level, so pad samples decode to 0
+    # pad with -1: it matches no level, so pad samples decode to 0
     # (padding with 0 would decode to centroid c_0 and corrupt the Gram)
     if (n_p, dl_p) != (n, dl):
         codes = jnp.pad(
@@ -240,7 +248,7 @@ def code_corr(
         in_specs=[
             pl.BlockSpec((1, bn, bd), lambda a, i, j, k: (a, k, i)),
             pl.BlockSpec((1, bn, bd), lambda a, i, j, k: (a, k, j)),
-            pl.BlockSpec(cents.shape, lambda a, i, j, k: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, bd, bd), lambda a, i, j, k: (a, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, dl_p, dr_p), jnp.float32),
@@ -254,23 +262,32 @@ def code_corr(
 # sign_corr_packed: XNOR + popcount Gram over bit-packed sign codes
 # ---------------------------------------------------------------------------
 
-def _popcount8(x: jax.Array) -> jax.Array:
-    """SWAR popcount of a uint8 array (pure shift/mask VPU ops)."""
-    v = x - ((x >> 1) & jnp.uint8(0x55))
-    v = (v & jnp.uint8(0x33)) + ((v >> 2) & jnp.uint8(0x33))
-    return (v + (v >> 4)) & jnp.uint8(0x0F)
+def _popcount32(v: jax.Array) -> jax.Array:
+    """SWAR popcount of an int32 array (shift/mask adds on the VPU).
+
+    Arithmetic right shifts are safe: every shift is followed by a mask
+    that clears the sign-filled bits, and from the third step on every
+    partial sum is non-negative."""
+    v = v - ((v >> 1) & 0x55555555)
+    v = (v & 0x33333333) + ((v >> 2) & 0x33333333)
+    v = (v + (v >> 4)) & 0x0F0F0F0F
+    v = v + (v >> 8)
+    v = v + (v >> 16)
+    return v & 0x3F
 
 
 def _sign_corr_packed_kernel(a_ref, b_ref, out_ref):
-    """Grid (b, d_l/bd, d_r/bd, nb/bb); accumulates XOR popcounts over bytes."""
+    """Grid (b, d_l/bd, d_r/bd, nw/bw); accumulates XOR popcounts over
+    int32 words, 8 output rows per step of the unrolled row loop."""
     @pl.when(pl.program_id(3) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a = a_ref[0]  # (bd, bb) uint8, feature-major packed bits
-    b = b_ref[0]
-    diff = _popcount8(a[:, None, :] ^ b[None, :, :])  # (bd, bd, bb) in [0, 8]
-    out_ref[0] += jnp.sum(diff.astype(jnp.int32), axis=-1)
+    b = b_ref[0]  # (bd, bw) int32, feature-major packed words
+    for r in range(0, a_ref.shape[1], 8):
+        a = a_ref[0, r:r + 8, :]
+        diff = _popcount32(a[:, None, :] ^ b[None, :, :])  # (8, bd, bw)
+        out_ref[0, r:r + 8, :] += jnp.sum(diff, axis=-1)
 
 
 @functools.partial(
@@ -281,7 +298,7 @@ def sign_corr_packed(
     packed_rhs: jax.Array | None = None,
     *,
     block_d: int = 128,
-    block_b: int = 128,
+    block_b: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
     """Sign-method Gram G = U^T U directly from bit-packed codes.
@@ -295,6 +312,8 @@ def sign_corr_packed(
         drop out of the identity below.
       n: true number of samples (bits) per row; nb == ceil(n / 8).
       packed_rhs: optional (d_r, nb) / (b, d_r, nb) right operand.
+      block_b: bytes per n-step, rounded up to whole 128-lane rows of
+        int32 words (512 bytes).
     Returns:
       (d_l, d_r) — batched: (b, d_l, d_r) — float32 Gram, exactly
       n - 2*popcount(xor): integer-exact, identical to ``sign_corr`` on the
@@ -308,26 +327,30 @@ def sign_corr_packed(
     b, dl, nb = packed.shape
     bv, dr, nbr = packed_rhs.shape
     assert (b, nb) == (bv, nbr), (packed.shape, packed_rhs.shape)
-    bd = min(block_d, _ceil_mult(max(dl, dr), 8))
-    bb = min(block_b, _ceil_mult(nb, 128))
-    dl_p, dr_p, nb_p = _ceil_mult(dl, bd), _ceil_mult(dr, bd), _ceil_mult(nb, bb)
-    if (dl_p, nb_p) != (dl, nb):
-        packed = jnp.pad(packed, ((0, 0), (0, dl_p - dl), (0, nb_p - nb)))
-    if (dr_p, nb_p) != (dr, nbr):
-        packed_rhs = jnp.pad(
-            packed_rhs, ((0, 0), (0, dr_p - dr), (0, nb_p - nbr)))
-    grid = (b, dl_p // bd, dr_p // bd, nb_p // bb)
+    bd = _d_block(max(dl, dr), block_d)
+    nw = -(-nb // 4)
+    bw = min(_ceil_mult(max(block_b // 4, 1), 128), _ceil_mult(nw, 128))
+    dl_p, dr_p, nw_p = _ceil_mult(dl, bd), _ceil_mult(dr, bd), _ceil_mult(nw, bw)
+
+    def words(p, d, d_p):
+        # zero pad bytes XOR to zero; the popcount sums every bit of a
+        # word, so the byte order inside the bitcast never matters
+        p = jnp.pad(p, ((0, 0), (0, d_p - d), (0, 4 * nw_p - nb)))
+        return jax.lax.bitcast_convert_type(
+            p.reshape(b, d_p, nw_p, 4), jnp.int32)
+
+    grid = (b, dl_p // bd, dr_p // bd, nw_p // bw)
     pop = pl.pallas_call(
         _sign_corr_packed_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bd, bb), lambda a, i, j, k: (a, i, k)),
-            pl.BlockSpec((1, bd, bb), lambda a, i, j, k: (a, j, k)),
+            pl.BlockSpec((1, bd, bw), lambda a, i, j, k: (a, i, k)),
+            pl.BlockSpec((1, bd, bw), lambda a, i, j, k: (a, j, k)),
         ],
         out_specs=pl.BlockSpec((1, bd, bd), lambda a, i, j, k: (a, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, dl_p, dr_p), jnp.int32),
         interpret=interpret,
-    )(packed, packed_rhs)
+    )(words(packed, dl, dl_p), words(packed_rhs, dr, dr_p))
     out = (n - 2 * pop[:, :dl, :dr]).astype(jnp.float32)
     return out if batched else out[0]
 

@@ -54,9 +54,22 @@ def _next_pow2(k: int) -> int:
     return 1 << max(0, (k - 1).bit_length())
 
 
+def _per_slot(fn, mesh):
+    """``fn`` over the slot axis, each device contracting its own slots
+    when a tenant mesh is given: the Pallas Gram kernels cannot be
+    partitioned automatically, and every slot's Gram is independent, so
+    the sharded result is the unsharded one bit for bit."""
+    if mesh is None:
+        return fn
+    from jax.sharding import PartitionSpec
+
+    return jax.shard_map(fn, mesh=mesh, in_specs=PartitionSpec("tenant"),
+                         out_specs=PartitionSpec("tenant"), check_vma=False)
+
+
 @functools.lru_cache(maxsize=None)
 def _codes_fold_stage(slots: int, block_n: int, d: int, method: str,
-                      rate: int, engine: GramEngine):
+                      rate: int, engine: GramEngine, mesh=None):
     """jit: (slots, block_n, d) int8 -> (slots, d, d) f32 per-slot Grams.
 
     Sign codes arrive as {-1, 0, +1} (0 — a padded row or a masked wire
@@ -64,7 +77,7 @@ def _codes_fold_stage(slots: int, block_n: int, d: int, method: str,
     wires were already mapped to ±1 on the host); per-symbol codes as
     bin indices with MASKED_CODE padding (decodes to 0 on every
     backend). One compile per (kind, slot bucket) serves every tick at
-    that bucket.
+    that bucket. ``mesh``: the tenant mesh the batch is sharded over.
     """
     if method == "sign":
         fn = engine.gram_batch
@@ -73,12 +86,12 @@ def _codes_fold_stage(slots: int, block_n: int, d: int, method: str,
         fn = functools.partial(engine.code_gram_batch, centroids=centroids)
     else:
         raise ValueError(f"serve folds quantized payloads, got {method!r}")
-    return jax.jit(fn)
+    return jax.jit(_per_slot(fn, mesh))
 
 
 @functools.lru_cache(maxsize=None)
 def _packed_fold_stage(slots: int, block_n: int, d: int,
-                       engine: GramEngine):
+                       engine: GramEngine, mesh=None):
     """jit: (slots, d, block_n/8) uint8 + (slots,) valid counts ->
     (slots, d, d) f32. Zero-padded tail bits xor to agreement under the
     XNOR+popcount kernel; the integer-exact uniform shift
@@ -86,10 +99,12 @@ def _packed_fold_stage(slots: int, block_n: int, d: int,
     same identity as ``StreamingGram.update_packed_batch``) — an all-zero
     padding slot lands exactly on 0.
     """
+    gram = _per_slot(
+        lambda batch: engine.packed_sign_gram_batch(batch, block_n), mesh)
+
     def f(batch, n_valid):
-        g = engine.packed_sign_gram_batch(batch, block_n)
-        return g - (jnp.float32(block_n)
-                    - n_valid.astype(jnp.float32))[:, None, None]
+        return gram(batch) - (jnp.float32(block_n)
+                              - n_valid.astype(jnp.float32))[:, None, None]
 
     return jax.jit(f)
 
@@ -180,7 +195,7 @@ class TenantTable:
             # drops out of the contraction exactly like padding rows
             batch[i, :p.n] = c
         stage = _codes_fold_stage(S, self.block_n, self.d, self.method,
-                                  self.rate, self._eng)
+                                  self.rate, self._eng, self._slot_mesh(S))
         g = np.asarray(stage(self._place(batch)), np.float64)
         return self._scatter(chunk, g)
 
@@ -195,7 +210,8 @@ class TenantTable:
             self._check(p)
             batch[i, :, :p.packed.shape[1]] = p.packed
             n_valid[i] = p.n
-        stage = _packed_fold_stage(S, self.block_n, self.d, self._eng)
+        stage = _packed_fold_stage(S, self.block_n, self.d, self._eng,
+                                   self._slot_mesh(S))
         g = np.asarray(stage(self._place(batch), jnp.asarray(n_valid)),
                        np.float64)
         return self._scatter(chunk, g)
@@ -275,14 +291,21 @@ class TenantTable:
         return {"solved": solved, "drifted": drifted,
                 "drift_edges": drift_edges}
 
-    def _place(self, arr: np.ndarray):
-        """Host batch -> device, sharded over the tenant mesh when one is
-        attached and divides the slot bucket (slot buckets are powers of
-        two, and so is the mesh — see ``launch.mesh.make_tenant_mesh``)."""
-        x = jnp.asarray(arr)
+    def _slot_mesh(self, slots: int):
+        """The tenant mesh when one is attached and divides the slot
+        bucket (slot buckets are powers of two, and so is the mesh — see
+        ``launch.mesh.make_tenant_mesh``); else None."""
         mesh = self.mesh
         if (mesh is not None and mesh.devices.size > 1
-                and arr.shape[0] % mesh.devices.size == 0):
+                and slots % mesh.devices.size == 0):
+            return mesh
+        return None
+
+    def _place(self, arr: np.ndarray):
+        """Host batch -> device, sharded over :meth:`_slot_mesh`."""
+        x = jnp.asarray(arr)
+        mesh = self._slot_mesh(arr.shape[0])
+        if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
             x = jax.device_put(

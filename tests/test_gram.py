@@ -4,6 +4,10 @@ odd (non-block-multiple) shapes, and streaming-vs-batch through the engine.
 All pallas paths run interpret=True on this CPU container; sign Grams are
 integer-exact so every comparison there is array_equal, not allclose.
 """
+import os
+import subprocess
+import sys
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -102,6 +106,38 @@ def test_code_corr_parity(rate, n, d):
 
 
 # ---------------------------------------------------------------------------
+# the TPU forms of the packed and code kernels, across output-tile regimes:
+# one padded tile (20, 33), two 128-lane tiles (130), many (1025)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("d", [20, 33, 130, 1025])
+def test_sign_corr_packed_matches_numpy_engine(d):
+    n = 203  # not a byte multiple: zero tail bits
+    u = _signs(n, d, seed=d)
+    packed = _pack(u)
+    got = np.asarray(PALLAS.packed_sign_gram(packed, n))
+    assert np.array_equal(got, NUMPY.packed_sign_gram(np.asarray(packed), n))
+
+
+@pytest.mark.parametrize("d", [20, 33, 130, 1025])
+def test_code_corr_within_f32_bound_of_numpy_engine(d):
+    """The in-kernel decode contracts f32 tiles at HIGHEST precision, so
+    it differs from the numpy engine's f32 Gram only by summation order:
+    |G - G_np| <= 2 gamma_(n+1) sum_i |u_ij u_ik| (gamma_m = m u / (1 -
+    m u), u = 2^-24), and sum_i |u_ij u_ik| <= sqrt(G_jj G_kk)."""
+    n = 200
+    q = PerSymbolQuantizer(4)
+    x = jax.random.normal(jax.random.key(d), (n, d))
+    codes = np.asarray(q.encode(x), np.int8)
+    got = np.asarray(PALLAS.code_gram(jnp.asarray(codes), q.centroids_np))
+    want = NUMPY.code_gram(codes, q.centroids_np)
+    u32 = 2.0 ** -24
+    gamma = (n + 1) * u32 / (1 - (n + 1) * u32)
+    diag = np.sqrt(np.diagonal(want))
+    assert (np.abs(got - want) <= 2 * gamma * np.outer(diag, diag)).all()
+
+
+# ---------------------------------------------------------------------------
 # GramEngine: backend dispatch parity
 # ---------------------------------------------------------------------------
 
@@ -135,6 +171,21 @@ def test_engine_auto_resolution_and_env_override(monkeypatch):
     monkeypatch.delenv("REPRO_GRAM_BACKEND")
     with pytest.raises(ValueError):
         GramEngine(backend="tensorflow").resolve()
+
+
+def test_import_starts_no_backend():
+    """Importing the library takes no device: a process that imports it
+    can still hand the chip to a child (one process per chip)."""
+    code = ("import repro, repro.core, repro.kernels, repro.serve\n"
+            "from jax._src import xla_bridge\n"
+            "print(xla_bridge.backends_are_initialized())")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300,
+                       env=dict(os.environ, PYTHONPATH=src))
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == "False"
 
 
 def test_set_default_engine_roundtrip():
@@ -258,14 +309,16 @@ def test_gram_batch_matches_per_element():
 
 
 def test_gram_batch_rectangular_f32():
+    # the unbatched f32 Gram runs as a batch of one through the batched
+    # contraction, so the two forms round identically: equal, not close
     rng = np.random.default_rng(8)
     u = jnp.asarray(rng.normal(size=(2, 64, 5)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(2, 64, 9)).astype(np.float32))
     got = np.asarray(XLA.gram_batch(u, v))
     assert got.shape == (2, 5, 9)
     for i in range(2):
-        np.testing.assert_allclose(
-            got[i], np.asarray(XLA.gram(u[i], v[i])), rtol=1e-6)
+        np.testing.assert_array_equal(
+            got[i], np.asarray(XLA.gram(u[i], v[i])))
 
 
 def test_code_gram_batch_matches_and_masks():

@@ -134,7 +134,11 @@ def test_d_block_picks_small_pad_tiles():
     assert _d_block(100, 256) == 128
     assert _d_block(130, 256) == 256   # past PAD_TILES: 128-multiple
     assert _d_block(1025, 256) == 256  # never above block_d
-    assert _d_block(100, 64) == 64     # respects a small block_d
+    assert _d_block(60, 64) == 64      # respects a small block_d
+    # a tile narrower than 128 lanes that does not span the output is not
+    # a legal TPU block: a small block_d rounds up to the lane tiling
+    assert _d_block(100, 64) == 128
+    assert _d_block(1024, 64) == 128
     assert tuple(PAD_TILES) == (32, 64, 128)
 
 
@@ -201,6 +205,69 @@ def test_autotune_never_sweeps_under_trace(tmp_path, monkeypatch):
         assert np.array_equal(np.asarray(got), np.asarray(XLA.gram(u)))
     finally:
         clear_autotune_cache()
+
+
+def test_autotune_reports_failed_candidates(tmp_path, monkeypatch, capsys):
+    """A candidate that raises is named on stderr and skipped; the sweep
+    still picks a winner from the rest, and the key names the device."""
+    monkeypatch.setenv(gram_mod.AUTOTUNE_CACHE_ENV, str(tmp_path / "c.json"))
+    monkeypatch.delenv(gram_mod.AUTOTUNE_ENV, raising=False)
+    real = gram_mod._time_config
+
+    def flaky(engine, cfg, *a):
+        if cfg.d_tile is not None:
+            raise ValueError("refused by the compiler")
+        return real(engine, cfg, *a)
+
+    monkeypatch.setattr(gram_mod, "_time_config", flaky)
+    clear_autotune_cache()
+    try:
+        win = GramEngine(backend="xla", autotune=True).tune("int8", 64, 300)
+        assert win.d_tile is None
+        err = capsys.readouterr().err
+        assert "skipped" in err and "refused by the compiler" in err
+        key = gram_mod._tune_key("int8", 64, 300, "xla")
+        assert jax.devices()[0].device_kind in key
+    finally:
+        clear_autotune_cache()
+
+
+def test_autotune_raises_when_every_candidate_fails(tmp_path, monkeypatch):
+    monkeypatch.setenv(gram_mod.AUTOTUNE_CACHE_ENV, str(tmp_path / "c.json"))
+    monkeypatch.delenv(gram_mod.AUTOTUNE_ENV, raising=False)
+
+    def broken(*a):
+        raise ValueError("refused by the compiler")
+
+    monkeypatch.setattr(gram_mod, "_time_config", broken)
+    clear_autotune_cache()
+    try:
+        with pytest.raises(RuntimeError, match="every candidate failed"):
+            GramEngine(backend="xla", autotune=True).tune("int8", 64, 48)
+    finally:
+        clear_autotune_cache()
+
+
+def test_memory_budget_needs_a_tpu_limit(monkeypatch):
+    """A TPU that reports no bytes_limit is an error, not an assumed 8 GiB;
+    a host backend keeps the heuristic."""
+
+    class Dev:
+        def __init__(self, platform):
+            self.platform, self.device_kind = platform, "test device"
+
+        def memory_stats(self):
+            return {}
+
+    monkeypatch.delenv(gram_mod.MEMORY_BUDGET_ENV, raising=False)
+    monkeypatch.setattr(gram_mod.jax, "devices", lambda: [Dev("tpu")])
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        gram_mod.default_memory_budget()
+    monkeypatch.setenv(gram_mod.MEMORY_BUDGET_ENV, str(1 << 30))
+    assert gram_mod.default_memory_budget() == 1 << 30
+    monkeypatch.delenv(gram_mod.MEMORY_BUDGET_ENV)
+    monkeypatch.setattr(gram_mod.jax, "devices", lambda: [Dev("cpu")])
+    assert gram_mod.default_memory_budget() == 8 << 30
 
 
 def test_candidate_configs_respect_budget():
