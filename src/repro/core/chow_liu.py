@@ -30,6 +30,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.spans import span, spanned
 from .strategy import Strategy, as_strategy
 
 
@@ -199,9 +200,16 @@ def boruvka_mst_batch(weights: jax.Array, chunk: int | None = None
 
 
 def adjacency_to_edges(adj) -> list[tuple[int, int]]:
-    """Explicit host step: symmetric bool adjacency -> canonical edge list."""
-    iu, ju = np.nonzero(np.triu(np.asarray(adj), k=1))
-    return [(int(a), int(b)) for a, b in zip(iu, ju)]
+    """Explicit host step: symmetric bool adjacency -> canonical edge list.
+
+    The read-back (which waits for the device) is one
+    ``repro.structure.fetch`` span, the host extraction one
+    ``repro.structure.edges`` span."""
+    with span("structure.fetch"):
+        adj = np.asarray(adj)
+    with span("structure.edges"):
+        iu, ju = np.nonzero(np.triu(adj, k=1))
+        return [(int(a), int(b)) for a, b in zip(iu, ju)]
 
 
 # --------------------------------------------------------------------------
@@ -237,6 +245,7 @@ def learn_structure_jit(
     return boruvka_mst(estimators.strategy_weights(x, strategy, engine=engine))
 
 
+@spanned("learn_structure")
 def learn_structure(
     x,
     method: str = "sign",
@@ -260,6 +269,9 @@ def learn_structure(
 
     With ``backend='boruvka'`` (``strategy.mst``) the weights feed the
     device solver directly; only the final edge list crosses to the host.
+
+    Each call is one ``repro.learn_structure`` span; the MWST solve (its
+    dispatch, for Boruvka) is one ``repro.structure.mst`` span inside it.
     """
     from . import estimators
 
@@ -271,5 +283,8 @@ def learn_structure(
     x = jnp.asarray(x)
     w = estimators.strategy_weights(x, strategy, engine=engine)
     if strategy.mst == "boruvka":
-        return adjacency_to_edges(boruvka_mst(w))
-    return kruskal_mst(np.asarray(w))
+        with span("structure.mst"):
+            adj = boruvka_mst(w)
+        return adjacency_to_edges(adj)
+    with span("structure.mst"):
+        return kruskal_mst(np.asarray(w))

@@ -28,6 +28,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.spans import span
 from .gram import GramEngine, resolve_engine
 from .strategy import Strategy
 
@@ -804,6 +805,10 @@ def strategy_weights(
     dispatch to their planes (the budget allocation is derived from the
     static sample count here — pass explicit ``rates`` through the batch
     entry point for bucketed sweeps).
+
+    On the gather channel the three stages are the spans
+    ``repro.structure.encode``, ``.gram`` and ``.weights``: eagerly they
+    cover each stage's dispatch; under ``jit`` they record trace time only.
     """
     ch = strategy.channel
     if ch.kind == "mac":
@@ -811,9 +816,12 @@ def strategy_weights(
     if ch.kind == "budget":
         rates = ch.column_rates(x.shape[0], x.shape[1], strategy.rate)
         return budget_weights_batch(x, strategy, rates, engine=engine)
-    payload = strategy_payload(x, strategy)
-    gram = payload_gram(payload, strategy, engine=engine)
-    return weights_from_gram(gram, x.shape[0], strategy)
+    with span("structure.encode"):
+        payload = strategy_payload(x, strategy)
+    with span("structure.gram"):
+        gram = payload_gram(payload, strategy, engine=engine)
+    with span("structure.weights"):
+        return weights_from_gram(gram, x.shape[0], strategy)
 
 
 def strategy_weights_batch(

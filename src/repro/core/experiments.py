@@ -86,6 +86,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.spans import span, spanned
 from . import estimators, glasso, sampler, trees
 from . import path as path_engine
 from .path import PathPlan
@@ -383,6 +384,8 @@ class TrialResult:
     #: sparse supports: micro-F1 2*shared/(est+true) recovered exactly
     #: from the integer edge-count channels
     edge_f1: dict[str, list[float]]
+    #: wall seconds of the whole ``run_trials`` call, host tree draws
+    #: included (the interval of its ``repro.run_trials`` span)
     seconds: float
     #: host syncs the whole sweep performed — exactly 1 (the metric-tensor
     #: device_get); the sweep body never touches the host
@@ -461,21 +464,23 @@ def _plan_setup(
     Keyed on exactly the plan fields the ground truth depends on — NOT ns
     / strategies / buckets — so repeated ``run_trials`` calls on the same
     (or a re-scoped) plan skip the O(reps * d) Pruefer/BFS host loop and
-    the per-rep key folds entirely.
+    the per-rep key folds entirely. A miss records one
+    ``repro.sweep.draw`` span.
     """
-    parents = np.zeros((reps, d), np.int32)
-    rhos = np.zeros((reps, d), np.float32)
-    for rep in range(reps):
-        rng = np.random.default_rng(seed0 + rep)
-        edges = _draw_tree(tree, d, rng)
-        w = rng.uniform(rho_min, rho_max, size=d - 1)
-        parents[rep], rhos[rep], _ = trees.topological_parents(d, edges, w)
-    parents_j = jnp.asarray(parents)
-    rhos_j = jnp.asarray(rhos)
-    adj_true = trees.adjacency_from_parents(parents_j)
-    keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
-        jax.random.key(seed0), jnp.arange(reps, dtype=jnp.uint32))
-    return parents_j, rhos_j, adj_true, keys
+    with span("sweep.draw"):
+        parents = np.zeros((reps, d), np.int32)
+        rhos = np.zeros((reps, d), np.float32)
+        for rep in range(reps):
+            rng = np.random.default_rng(seed0 + rep)
+            edges = _draw_tree(tree, d, rng)
+            w = rng.uniform(rho_min, rho_max, size=d - 1)
+            parents[rep], rhos[rep], _ = trees.topological_parents(d, edges, w)
+        parents_j = jnp.asarray(parents)
+        rhos_j = jnp.asarray(rhos)
+        adj_true = trees.adjacency_from_parents(parents_j)
+        keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+            jax.random.key(seed0), jnp.arange(reps, dtype=jnp.uint32))
+        return parents_j, rhos_j, adj_true, keys
 
 
 def _setup_key(plan: TrialPlan):
@@ -526,22 +531,24 @@ def _sparse_plan_setup(
     float32 Cholesky factors of the implied unit-variance covariances
     (the row-keyed sampler's mixers); ``adj_true`` the (reps, d, d) bool
     supports; ``keys`` the same per-rep fold_in streams as
-    :func:`_plan_setup`.
+    :func:`_plan_setup` (and, like it, one ``repro.sweep.draw`` span a
+    miss).
     """
-    chols = np.zeros((reps, d, d), np.float32)
-    adj = np.zeros((reps, d, d), bool)
-    for rep in range(reps):
-        rng = np.random.default_rng(seed0 + rep)
-        theta = glasso.random_sparse_precision(
-            d, density, rng, strength=(rho_min, rho_max))
-        cov = np.linalg.inv(theta)
-        chols[rep] = np.linalg.cholesky(cov)
-        a = np.abs(theta) > 1e-8
-        np.fill_diagonal(a, False)
-        adj[rep] = a
-    keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
-        jax.random.key(seed0), jnp.arange(reps, dtype=jnp.uint32))
-    return jnp.asarray(chols), jnp.asarray(adj), keys
+    with span("sweep.draw"):
+        chols = np.zeros((reps, d, d), np.float32)
+        adj = np.zeros((reps, d, d), bool)
+        for rep in range(reps):
+            rng = np.random.default_rng(seed0 + rep)
+            theta = glasso.random_sparse_precision(
+                d, density, rng, strength=(rho_min, rho_max))
+            cov = np.linalg.inv(theta)
+            chols[rep] = np.linalg.cholesky(cov)
+            a = np.abs(theta) > 1e-8
+            np.fill_diagonal(a, False)
+            adj[rep] = a
+        keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+            jax.random.key(seed0), jnp.arange(reps, dtype=jnp.uint32))
+        return jnp.asarray(chols), jnp.asarray(adj), keys
 
 
 def _sparse_setup_key(plan: TrialPlan):
@@ -1564,7 +1571,8 @@ def _package_result(
 
 
 def _host_kruskal_trials(
-    plan: TrialPlan, engine: GramEngine, data_axis: str, model_axis: str
+    plan: TrialPlan, engine: GramEngine, data_axis: str, model_axis: str,
+    t0: float,
 ) -> TrialResult:
     """The ``mst="host_kruskal"`` escape hatch: device weights stage, host
     MWST + metrics.
@@ -1582,7 +1590,6 @@ def _host_kruskal_trials(
     fkeys = (fault_trial_keys(faults, plan.reps)
              if faults is not None else None)
     lead = () if faults is None else (fkeys,)
-    t0 = time.perf_counter()
     ws = []
     fsums = []
     needs_rates = _needs_rates(plan.strategies)
@@ -1632,6 +1639,7 @@ def _host_kruskal_trials(
                                    "metrics_chunk": None})
 
 
+@spanned("run_trials")
 def run_trials(
     plan: TrialPlan,
     *,
@@ -1701,7 +1709,15 @@ def run_trials(
     CommReports). A ZERO-fault plan still runs the fault path and is
     bit-identical to ``faults=None``; fault-enabled mesh runs keep the
     1-vs-N device parity (both pinned by CI).
+
+    ``TrialResult.seconds`` runs from the top of the call, host tree
+    draws included, to the packaging of the result: the interval of the
+    call's ``repro.run_trials`` span. Inside it, each of ``plan.ns``
+    records a ``repro.sweep.point`` span (stage lookup and dispatch), the
+    read-back a ``repro.sweep.sync`` span and the host packaging after it
+    a ``repro.sweep.report`` span.
     """
+    t0 = time.perf_counter()
     engine = resolve_engine(engine)
     labels = [s.label for s in plan.strategies]
     if len(set(labels)) != len(labels):
@@ -1729,7 +1745,7 @@ def run_trials(
             raise ValueError(
                 "mst='host_kruskal' is a tree-plane escape hatch; sparse "
                 "plans solve glasso, not an MWST")
-        return _host_kruskal_trials(plan, engine, data_axis, model_axis)
+        return _host_kruskal_trials(plan, engine, data_axis, model_axis, t0)
     shards = 1
     wire_plane = False
     if mesh is not None:
@@ -1841,71 +1857,71 @@ def run_trials(
 
     point_sums = []
     fault_sums = []
-    t0 = time.perf_counter()
     if warm_thread is not None:
         warm_thread.start()
     for n in plan.ns:
-        n_pad = plan.bucket_for(n)
-        n_valid = jnp.asarray(n, jnp.int32)
-        if mesh is None:
-            pre = prewarmed.pop((n_pad, n), None)
-            if pre is not None:
-                pre[0].join()
-            if pre is not None and pre[1]:
-                out = pre[1][0]
-            else:  # not prewarmed (or its thread failed): compute inline
-                out = stage_fn(plan.strategies, n_pad, engine, faults)(
-                    keys, *lead, *gt_args, n_valid, *rates_tail(n))
-            if faults is None:
-                w = out
+        with span("sweep.point"):
+            n_pad = plan.bucket_for(n)
+            n_valid = jnp.asarray(n, jnp.int32)
+            if mesh is None:
+                pre = prewarmed.pop((n_pad, n), None)
+                if pre is not None:
+                    pre[0].join()
+                if pre is not None and pre[1]:
+                    out = pre[1][0]
+                else:  # not prewarmed (or its thread failed): compute inline
+                    out = stage_fn(plan.strategies, n_pad, engine, faults)(
+                        keys, *lead, *gt_args, n_valid, *rates_tail(n))
+                if faults is None:
+                    w = out
+                else:
+                    w, fsum = out
+                    fault_sums.append(fsum)
+                if warm_thread is not None:
+                    warm_thread.join()
+                    warm_thread = None
+                point_sums.append(
+                    metrics_fn(w, adj_true, n_valid) if path_mode
+                    else metrics_fn(w, adj_true))
+            elif sparse:
+                corr_fn = (
+                    _sparse_wire_corr_fn(
+                        plan.strategies, n_pad, engine, mesh, data_axis,
+                        model_axis, faults)
+                    if wire_plane else
+                    _sparse_sharded_corr_fn(
+                        plan.strategies, n_pad, engine, mesh, data_axis,
+                        faults))
+                out = corr_fn(key_data, *lead_data, *gt_args, n_valid,
+                              *rates_tail(n))
+                if faults is None:
+                    corr = out
+                else:
+                    corr, fsum = out
+                    fault_sums.append(fsum)
+                # gather the rep-sharded statistics onto one device (a d2d
+                # copy, NOT a host sync) so the solve+metric executable is the
+                # single-device one — bit-identical results by construction
+                corr = jax.device_put(corr, jax.devices()[0])
+                point_sums.append(
+                    metrics_fn(corr, adj_true, n_valid) if path_mode
+                    else metrics_fn(corr, adj_true))
             else:
-                w, fsum = out
-                fault_sums.append(fsum)
-            if warm_thread is not None:
-                warm_thread.join()
-                warm_thread = None
-            point_sums.append(
-                metrics_fn(w, adj_true, n_valid) if path_mode
-                else metrics_fn(w, adj_true))
-        elif sparse:
-            corr_fn = (
-                _sparse_wire_corr_fn(
-                    plan.strategies, n_pad, engine, mesh, data_axis,
-                    model_axis, faults)
-                if wire_plane else
-                _sparse_sharded_corr_fn(
-                    plan.strategies, n_pad, engine, mesh, data_axis,
-                    faults))
-            out = corr_fn(key_data, *lead_data, *gt_args, n_valid,
-                          *rates_tail(n))
-            if faults is None:
-                corr = out
-            else:
-                corr, fsum = out
-                fault_sums.append(fsum)
-            # gather the rep-sharded statistics onto one device (a d2d
-            # copy, NOT a host sync) so the solve+metric executable is the
-            # single-device one — bit-identical results by construction
-            corr = jax.device_put(corr, jax.devices()[0])
-            point_sums.append(
-                metrics_fn(corr, adj_true, n_valid) if path_mode
-                else metrics_fn(corr, adj_true))
-        else:
-            point_fn = (
-                _wire_point_fn(
-                    plan.strategies, n_pad, engine, mesh, data_axis,
-                    model_axis, faults, chunk)
-                if wire_plane else
-                _sharded_point_fn(
-                    plan.strategies, n_pad, engine, mesh, data_axis,
-                    faults, chunk))
-            out = point_fn(key_data, *lead_data, *gt_args, adj_true,
-                           n_valid, *rates_tail(n))
-            if faults is None:
-                point_sums.append(out)
-            else:
-                point_sums.append(out[0])
-                fault_sums.append(out[1])
+                point_fn = (
+                    _wire_point_fn(
+                        plan.strategies, n_pad, engine, mesh, data_axis,
+                        model_axis, faults, chunk)
+                    if wire_plane else
+                    _sharded_point_fn(
+                        plan.strategies, n_pad, engine, mesh, data_axis,
+                        faults, chunk))
+                out = point_fn(key_data, *lead_data, *gt_args, adj_true,
+                               n_valid, *rates_tail(n))
+                if faults is None:
+                    point_sums.append(out)
+                else:
+                    point_sums.append(out[0])
+                    fault_sums.append(out[1])
     # (S, len(ns), 3) metric tensor, still on device; THE host sync.
     # host_syncs counts actual read-backs (the += convention every host
     # touch in this loop must follow), so the one_sync_per_sweep checks in
@@ -1925,25 +1941,26 @@ def run_trials(
         means = jnp.stack(point_sums, axis=1) / plan.reps
         extras = None
     bundle = (means, extras)
-    if faults is None:
-        m, host_extras = jax.device_get(jax.block_until_ready(bundle))
-        fsums = None
-    else:
-        (m, host_extras), fsums = jax.device_get(jax.block_until_ready(
-            (bundle, jnp.stack(fault_sums))))
+    with span("sweep.sync"):
+        if faults is None:
+            m, host_extras = jax.device_get(jax.block_until_ready(bundle))
+            fsums = None
+        else:
+            (m, host_extras), fsums = jax.device_get(jax.block_until_ready(
+                (bundle, jnp.stack(fault_sums))))
     syncs += 1
-    seconds = time.perf_counter() - t0
 
-    comm = _comm_reports(plan, engine, data_axis, model_axis, wire_plane,
-                         fault_sums=fsums)
-    return _package_result(
-        plan, m, seconds=seconds, host_syncs=syncs, comm=comm,
-        mesh_devices=(mesh.size if mesh is not None else 1),
-        faults=_fault_stats(plan, fsums),
-        tiling={"memory_budget_bytes": plan.effective_memory_budget,
-                "d_tile": engine.d_tile, "n_chunk": engine.n_chunk,
-                "metrics_chunk": chunk},
-        path_telemetry=_path_stats(plan, host_extras))
+    with span("sweep.report"):
+        comm = _comm_reports(plan, engine, data_axis, model_axis, wire_plane,
+                             fault_sums=fsums)
+        return _package_result(
+            plan, m, seconds=time.perf_counter() - t0, host_syncs=syncs,
+            comm=comm, mesh_devices=(mesh.size if mesh is not None else 1),
+            faults=_fault_stats(plan, fsums),
+            tiling={"memory_budget_bytes": plan.effective_memory_budget,
+                    "d_tile": engine.d_tile, "n_chunk": engine.n_chunk,
+                    "metrics_chunk": chunk},
+            path_telemetry=_path_stats(plan, host_extras))
 
 
 # --------------------------------------------------------------------------

@@ -61,6 +61,11 @@ leading grid dimension — grid (b, i, j, k) with one program per (trial,
 output tile, n-step) — not a ``vmap`` of ``pallas_call``, so a whole
 Monte-Carlo trial axis (``core.experiments``) runs as ONE kernel launch
 and the trial loop never re-enters the dispatch path.
+
+Each ``pallas_call`` passes ``name=`` its public function's name, which
+is the kernel's instruction name in the compiled program and its op name
+in a device trace; without it the name follows whatever jitted function
+encloses the call.
 """
 from __future__ import annotations
 
@@ -157,6 +162,7 @@ def sign_corr(
     grid = (b, dl_p // bd, dr_p // bd, n_p // bn)
     out = pl.pallas_call(
         _sign_corr_kernel,
+        name="sign_corr",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bn, bd), lambda a, i, j, k: (a, k, i)),
@@ -244,6 +250,7 @@ def code_corr(
     grid = (b, dl_p // bd, dr_p // bd, n_p // bn)
     out = pl.pallas_call(
         _code_corr_kernel,
+        name="code_corr",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bn, bd), lambda a, i, j, k: (a, k, i)),
@@ -342,6 +349,7 @@ def sign_corr_packed(
     grid = (b, dl_p // bd, dr_p // bd, nw_p // bw)
     pop = pl.pallas_call(
         _sign_corr_packed_kernel,
+        name="sign_corr_packed",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bd, bw), lambda a, i, j, k: (a, i, k)),

@@ -1,5 +1,8 @@
 """On-device trial plane: Strategy API, vmapped MWST, device metrics,
 batched sampler, and run_trials parity with the reference loop."""
+import dataclasses
+import time
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -240,6 +243,23 @@ def test_run_trials_shapes_and_telemetry():
         assert all(0.0 <= e <= 1.0 for e in errs)
     # more data can't make the unquantized method catastrophically worse
     assert res.error_rate["original"][1] <= res.error_rate["original"][0] + 0.5
+
+
+def test_run_trials_seconds_cover_the_host_tree_draws(monkeypatch):
+    from repro.core import experiments
+
+    def slow(*args, _draw=experiments._draw_tree):
+        time.sleep(0.1)
+        return _draw(*args)
+
+    plan = TrialPlan(d=6, ns=(64,), strategies=(Strategy("sign"),), reps=4)
+    run_trials(plan)  # compile outside the timed call
+    monkeypatch.setattr(experiments, "_draw_tree", slow)
+    fresh = dataclasses.replace(plan, seed0=plan.seed0 + 1)  # a draw miss
+    t0 = time.perf_counter()
+    res = run_trials(fresh)
+    wall = time.perf_counter() - t0
+    assert 4 * 0.1 <= res.seconds <= wall
 
 
 def test_run_trials_deterministic():

@@ -13,6 +13,7 @@ only one process may hold the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,10 +59,20 @@ def _compile_text(fn, one_chip, *operands) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_names(text: str) -> set[str]:
+    """Instruction names of the compiled program's Mosaic kernels, numeric
+    suffix dropped: the op names a device trace shows for them."""
+    return {re.sub(r"\.\d+$", "",
+                   ln.split(" = ", 1)[0].split()[-1].lstrip("%"))
+            for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln}
+
+
 @pytest.mark.parametrize("shape", [(60, 4096, 20), (65536, 1024)])
 def test_sign_corr_compiles_for_v5e(one_chip, shape):
     text = _compile_text(sign_corr, one_chip, (shape, jnp.int8))
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"sign_corr"}
 
 
 @pytest.mark.parametrize("shape", [(60, 4096, 20), (8192, 1024)])
@@ -69,14 +80,17 @@ def test_code_corr_compiles_for_v5e(one_chip, shape):
     text = _compile_text(code_corr, one_chip, (shape, jnp.int8),
                          ((16,), jnp.float32))
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"code_corr"}
 
 
 @pytest.mark.parametrize("shape,n", [((60, 20, 512), 4096),
                                      ((4096, 8192), 65536)])
 def test_sign_corr_packed_compiles_for_v5e(one_chip, shape, n):
+    # compiled inside another jitted function: the name stays the kernel's
     text = _compile_text(lambda p: sign_corr_packed(p, n), one_chip,
                          (shape, jnp.uint8))
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"sign_corr_packed"}
 
 
 @pytest.mark.parametrize("kind", ["codes", "packed"])
