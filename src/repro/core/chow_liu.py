@@ -6,15 +6,17 @@ Two MWST implementations with identical tie-breaking semantics:
   descending weight and union-find. Reference implementation (a spanning
   forest with the threshold at -inf).
 * ``boruvka_mst`` — TPU-native adaptation: Boruvka's algorithm is O(log d)
-  rounds of per-component max-reductions, which vectorizes as jnp reductions
-  and scatters — jit-able, vmap-able over stacked weight matrices, and
-  usable inside ``shard_map`` on device. The Kruskal algorithm is inherently
-  sequential (data-dependent union-find), so this is the hardware adaptation
-  of the paper's central-machine step.
+  rounds of per-component max-reductions, which vectorize as dense
+  compares and reductions (no scatter, gather or sort) — jit-able,
+  vmap-able over stacked weight matrices, and usable inside ``shard_map``
+  on device. The Kruskal algorithm is inherently sequential
+  (data-dependent union-find), so this is the hardware adaptation of the
+  paper's central-machine step.
 
 Both depend only on the ORDER of the weights (as the paper notes for
-Kruskal); we make ties well-defined by ranking flattened weights with a
-stable sort, so both algorithms agree exactly on any input.
+Kruskal); ties are broken by the smaller flat index in both, so the edge
+order is strict, the tree unique, and both algorithms agree exactly on
+any input.
 
 Device vs host flow: with ``backend="boruvka"`` the weight matrix feeds
 ``boruvka_mst`` directly as a JAX array and the result is the bool
@@ -97,23 +99,28 @@ def kruskal_mst(weights: np.ndarray) -> list[tuple[int, int]]:
 # Device-side Boruvka (jit-able, fixed shapes)
 # --------------------------------------------------------------------------
 
-def _rank_weights(weights: jax.Array) -> jax.Array:
-    """Replace weights by distinct integer ranks (order-preserving).
+def _edge_order(weights: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Per-edge sort keys ``(key, tie)`` of (d, d) weights, built
+    elementwise: no sort, no scatter.
 
-    MWST depends only on the weight order, so ranking is exact. Stable
-    argsort breaks ties by flat index; (j,k)/(k,j) ranks are unified by max,
-    which preserves inter-value order. Diagonal is forced to rank -1.
+    ``key`` is an order-preserving integer image of the weight in the
+    forms ``lax.sort`` compares (-0.0 as +0.0; every NaN alike, below
+    -inf); ``tie`` is the row-major flat index. Edge {j,k} takes the
+    larger of its (j,k) and (k,j) entries: the larger key, on equal keys
+    the smaller flat index. So edges rank by descending weight with ties
+    broken by SMALLER flat index first — Kruskal's stable descending sort
+    over triu indices — and no two edges rank alike.
     """
-    d = weights.shape[0]
-    flat = weights.reshape(-1)
-    # ties broken by SMALLER flat (row-major) index first — identical to
-    # Kruskal's stable descending sort over triu indices
-    order = jnp.argsort(-flat, stable=True)
-    ranks = jnp.zeros(d * d, jnp.int32).at[order].set(
-        jnp.arange(d * d, 0, -1, dtype=jnp.int32))
-    r = ranks.reshape(d, d)
-    r = jnp.maximum(r, r.T)
-    return jnp.where(jnp.eye(d, dtype=bool), -1, r)
+    w = weights.astype(jnp.float32)
+    d = w.shape[0]
+    w = jnp.where(w == 0, jnp.zeros_like(w), w)
+    bits = jax.lax.bitcast_convert_type(w, jnp.int32)
+    info = jnp.iinfo(jnp.int32)
+    key = jnp.where(bits < 0, bits ^ info.max, bits)
+    key = jnp.where(jnp.isnan(w), info.min + 1, key)   # info.min: no edge
+    tie = jnp.arange(d * d, dtype=jnp.int32).reshape(d, d)
+    own = (key > key.T) | ((key == key.T) & (tie < tie.T))
+    return jnp.where(own, key, key.T), jnp.where(own, tie, tie.T)
 
 
 @jax.jit
@@ -125,51 +132,59 @@ def boruvka_mst(weights: jax.Array) -> jax.Array:
     Returns:
       (d, d) bool adjacency of the MWST (symmetric).
 
+    Edges are ordered by :func:`_edge_order`, a strict total order, so the
+    tree is unique and equals :func:`kruskal_mst`'s. Each round every
+    component picks its best outgoing edge and merges across it. Every
+    step is an elementwise compare plus a reduction, so a round costs a
+    few O(d^2) passes and lowers to no scatter, gather or sort: on a TPU
+    those run element by element. A component's label is one of its
+    nodes, so a label indexes per-node vectors through the one-hot
+    reduction ``take``.
+
     The round body is idempotent once a single component remains, so the
-    while_loop batches correctly under ``vmap`` (trials that converge early
-    simply coast while the stragglers finish).
+    while_loop batches correctly under ``vmap`` (trials that converge
+    early simply coast while the stragglers finish).
     """
-    d = weights.shape[0]
-    W = _rank_weights(weights)  # distinct int ranks, diag = -1
-    n_jump = int(np.ceil(np.log2(max(d, 2)))) + 1
+    K, T = _edge_order(weights)
+    d = K.shape[0]
+    no_key = jnp.iinfo(jnp.int32).min
+    no_tie = jnp.iinfo(jnp.int32).max
+    node = jnp.arange(d, dtype=jnp.int32)
+
+    def take(v, i):  # v[i[j]] for every j
+        return jnp.where(node[:, None] == i, v[:, None], no_tie).min(0)
+
+    def jump(state):
+        f, _ = state
+        g = take(f, f)
+        return g, jnp.any(g != f)
 
     def round_body(state):
-        comp, sel, _ = state
-        cross = comp[:, None] != comp[None, :]
-        Wm = jnp.where(cross, W, -1)
-        best_w = Wm.max(axis=1)                      # (d,) best outgoing rank per node
-        best_k = Wm.argmax(axis=1).astype(jnp.int32)
-        # per-component champion rank
-        seg_best = jax.ops.segment_max(best_w, comp, num_segments=d)  # (d,) by label
-        has_edge = seg_best >= 0
-        is_best = (best_w == seg_best[comp]) & (best_w >= 0)
-        # champion node per component = smallest index among is_best
-        node_score = jnp.where(is_best, d - jnp.arange(d, dtype=jnp.int32), 0)
-        seg_node = jax.ops.segment_max(node_score, comp, num_segments=d)
-        j_star = d - seg_node                        # valid only where has_edge
-        valid = has_edge & (seg_node > 0)
-        j_sel = jnp.where(valid, j_star, 0).astype(jnp.int32)
-        k_sel = jnp.where(valid, best_k[j_sel], 0).astype(jnp.int32)
-        sel = sel.at[j_sel, k_sel].max(valid)
-        sel = sel.at[k_sel, j_sel].max(valid)
-        # merge component labels: parent[max] = min, then pointer-jump
-        cj, ck = comp[j_sel], comp[k_sel]
-        hi, lo = jnp.maximum(cj, ck), jnp.minimum(cj, ck)
-        hi = jnp.where(valid, hi, jnp.arange(d, dtype=jnp.int32))
-        lo = jnp.where(valid, lo, jnp.arange(d, dtype=jnp.int32))
-        parent = jnp.arange(d, dtype=jnp.int32).at[hi].min(lo)
-        parent = jax.lax.fori_loop(0, n_jump, lambda _, p: p[p], parent)
-        comp = parent[comp]
-        n_comp = jnp.sum(jnp.bincount(comp, length=d) > 0)
-        return comp, sel, n_comp
+        comp, chosen, _ = state
+        same = comp[:, None] == comp
+        # node j's best edge out of its component, then the component's
+        # best over its members: the champion, named by its tie
+        best_key = jnp.where(same, no_key, K).max(0)
+        best_tie = jnp.where(~same & (K == best_key), T, no_tie).min(0)
+        champ_key = jnp.where(same, best_key[:, None], no_key).max(0)
+        champ = jnp.where(same & (best_key[:, None] == champ_key),
+                          best_tie[:, None], no_tie).min(0)
+        # T[k, j] == champ[j] only at the champion's ends, j inside
+        chosen = chosen | (T == champ)
+        ends = (node[:, None] == champ // d) | (node[:, None] == champ % d)
+        across = jnp.where(ends & ~same, comp[:, None], no_tie).min(0)
+        hook = jnp.where(champ < no_tie, across, comp)
+        # components hook to the label across their champion; the two
+        # that chose one edge hook to each other, broken at the smaller
+        mutual = take(hook, hook) == comp
+        f = jnp.where(mutual & (comp < hook), comp, hook)
+        comp, _ = jax.lax.while_loop(lambda s: s[1], jump,
+                                     (f, jnp.asarray(True)))
+        return comp, chosen, jnp.sum(comp == node)
 
-    init = (
-        jnp.arange(d, dtype=jnp.int32),
-        jnp.zeros((d, d), dtype=bool),
-        jnp.asarray(d, dtype=jnp.int32),
-    )
-    _, sel, _ = jax.lax.while_loop(lambda s: s[2] > 1, round_body, init)
-    return sel
+    init = (node, jnp.zeros((d, d), dtype=bool), jnp.asarray(d, jnp.int32))
+    _, chosen, _ = jax.lax.while_loop(lambda s: s[2] > 1, round_body, init)
+    return chosen | chosen.T
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -180,12 +195,12 @@ def boruvka_mst_batch(weights: jax.Array, chunk: int | None = None
     ``chunk=None`` is the plain ``vmap`` (one fused launch for the whole
     trial stack). With ``chunk`` set, the batch streams through
     ``lax.map`` in ``chunk``-sized vmapped slabs, so the solver's
-    transient working set (the per-trial rank/component scratch) scales
+    transient working set (the per-trial key/component scratch) scales
     with ``chunk`` instead of b — the memory-budgeted metrics stage of
     ``experiments.run_trials`` at large d. Trials are independent, so the
     chunked result is bit-identical per trial to the full vmap; the batch
     zero-pads to a chunk multiple (an all-zero weight matrix still runs —
-    rank-based, weight values never matter — and is sliced off).
+    only the order of weights matters — and is sliced off).
     """
     b = weights.shape[0]
     if chunk is None or chunk >= b:
@@ -238,7 +253,7 @@ def learn_structure_jit(
     Pure and jit-able (``strategy``/``engine`` are trace-time constants);
     this is the per-trial unit the experiments engine vmaps. The MWST is
     always the device Boruvka solver — exactly equal to Kruskal by the
-    shared rank construction.
+    shared edge order.
     """
     from . import estimators
 

@@ -40,9 +40,9 @@ replaces it with a batched engine built from three stacked optimizations:
   (logical n*d*R bits vs bytes actually gathered) on ``TrialResult.comm``.
 
 The MWST inside the trial plane is the device Boruvka solver
-(exact-equal to host Kruskal by the shared rank construction);
+(exact-equal to host Kruskal by the shared edge order);
 ``run_trials(..., mst="host_kruskal")`` is the escape hatch for future
-solvers that break that rank equivalence — it pulls the weight tensors
+solvers that break that order equivalence — it pulls the weight tensors
 back in ONE stacked device_get and runs the host Kruskal + host metrics
 loop, metric-identical to the device path on the current estimators.
 
@@ -1667,10 +1667,10 @@ def run_trials(
     ``jax.transfer_guard_device_to_host("disallow")``.
 
     ``mst`` picks the MWST solver: ``"device"`` (default) is the on-device
-    Boruvka — exact-equal to host Kruskal by the shared rank construction
+    Boruvka — exact-equal to host Kruskal by the shared edge order
     (so a ``Strategy(mst='kruskal')`` measures identically here) —
     ``"host_kruskal"`` is the escape hatch for future solvers that break
-    that rank equivalence: the device weights are read back in one stacked
+    that order equivalence: the device weights are read back in one stacked
     ``device_get`` (host_syncs stays 1) and the MWST + metrics run as a
     host loop; metric-identical to the device path on the current
     estimators (pinned by test).

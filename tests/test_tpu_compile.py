@@ -1,9 +1,11 @@
-"""Compile-only checks of the Gram kernels for a described TPU v5e.
+"""Compile-only checks of the Gram kernels and the MST stage for a
+described TPU v5e.
 
-Each case lowers and compiles one kernel at a real width for one chip of a
-``v5e:2x2`` topology that is described, not attached (or, for the serving
-fold, over all four chips), and asserts that the kernel is in the
-compiled program (``tpu_custom_call``). Nothing runs, so these say
+Each case lowers and compiles one kernel or solver at a real width for one
+chip of a ``v5e:2x2`` topology that is described, not attached (or, for
+the serving fold, over all four chips), and asserts that the kernel is in
+the compiled program (``tpu_custom_call``), or that the solver compiled
+with no scatter, gather or sort. Nothing runs, so these say
 nothing about results or speed; they catch what the chip's compiler
 refuses and interpret mode accepts (unaligned blocks, too much VMEM,
 unsupported vector types, kernels left to automatic partitioning).
@@ -23,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
     SingleDeviceSharding
 
 from repro.core import GramEngine
+from repro.core.chow_liu import boruvka_mst, boruvka_mst_batch
 from repro.kernels.sign_corr import code_corr, sign_corr, sign_corr_packed
 from repro.serve import table
 
@@ -91,6 +94,20 @@ def test_sign_corr_packed_compiles_for_v5e(one_chip, shape, n):
                          (shape, jnp.uint8))
     assert "tpu_custom_call" in text
     assert _kernel_names(text) == {"sign_corr_packed"}
+
+
+@pytest.mark.parametrize("fn,shape", [
+    (boruvka_mst, (1024, 1024)),
+    (jax.vmap(boruvka_mst), (6000, 20, 20)),
+    (boruvka_mst_batch, (6000, 20, 20)),
+], ids=["single_d1024", "vmap", "batch"])
+def test_boruvka_compiles_for_v5e(one_chip, fn, shape):
+    """The structure cell's d=1024 solve and the sweep's stack of 6000
+    d=20 trials, vmapped and laid along lanes."""
+    text = _compile_text(fn, one_chip, (shape, jnp.float32))
+    ops = set(re.findall(r"\s([a-z][\w-]*)\(%", text))
+    assert "while" in ops
+    assert not ops & {"scatter", "gather", "sort"}
 
 
 @pytest.mark.parametrize("kind", ["codes", "packed"])
