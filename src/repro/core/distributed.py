@@ -33,7 +33,11 @@ carried by :class:`WirePlan` (the executable companion of the declarative
 
 :func:`build_weights_fn` shard_maps the composed
 ``encode -> wire -> central`` chain (:meth:`WirePlan.local_weights`) for
-one dataset; ``experiments.run_trials(plan, mesh=("data","model"))`` runs
+one dataset and jits it, once per (mesh, strategy, axes, engine, glasso
+steps, path): a later call with the same mesh, strategy and shapes
+traces, lowers and compiles nothing. ``experiments.clear_compile_caches``
+drops these runtimes with the trial plane's stages.
+``experiments.run_trials(plan, mesh=("data","model"))`` runs
 the SAME stages over the Monte-Carlo trial plane — trials sharded over
 ``data``, features over ``model`` — with per-strategy ``CommReport``
 telemetry and bit-identical metrics to the single-device engine.
@@ -57,6 +61,7 @@ Two compute placements are provided (see EXPERIMENTS.md §Perf):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Literal
 
 import numpy as np
@@ -64,8 +69,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.spans import span, spanned
 from . import estimators, glasso
-from .chow_liu import boruvka_mst
+from .chow_liu import adjacency_to_edges, boruvka_mst, kruskal_mst
 from .glasso import DEFAULT_STEPS as GLASSO_STEPS
 from .gram import GramEngine
 from .path import PathPlan, glasso_path_select
@@ -363,14 +369,11 @@ class WirePlan:
         if data_sharded:
             gram = jax.lax.psum(gram, self.data_axis)
         if s.placement == "rowblock":
-            # tiled all_gather replicates the row blocks; VMA inference
-            # cannot prove replication for all_gather outputs, hence
-            # check_vma=False on the shard_map below.
+            # the tiled all_gather replicates the row blocks; under the
+            # replicated placement every model rank already holds the
+            # whole Gram
             gram = jax.lax.all_gather(
                 gram, self.model_axis, axis=gram.ndim - 2, tiled=True)
-        elif data_sharded:
-            # replicated over model by construction; make it explicit
-            gram = jax.lax.pmean(gram, self.model_axis)
         return gram
 
     def central_corr(
@@ -547,6 +550,11 @@ def build_weights_fn(
     engine: GramEngine the Gram contractions dispatch through (must be a
     traced backend — 'pallas' or 'xla' — inside shard_map; None = process
     default, which auto-selects per platform).
+
+    Returns ``(runtime, sharding)``: the jitted shard_map, which takes
+    (n, d) samples placed with ``sharding`` (``P(data_axis,
+    model_axis)``). Both are built once per (mesh, strategy, axes, engine,
+    ``glasso_steps``, ``path``) and the same objects are returned after.
     """
     strat = _as_wire_strategy(strategy, method, rate, compute, wire)
     if strat.channel.kind != "gather":
@@ -558,17 +566,37 @@ def build_weights_fn(
         raise ValueError(
             "path= is the sparse plane's regularization-path engine; "
             "tree strategies have no penalty to select")
-    plan = WirePlan(strat, data_axis=data_axis, model_axis=model_axis,
-                    engine=engine, glasso_steps=glasso_steps, path=path)
-    in_spec = P(data_axis, model_axis)
-    inner = jax.shard_map(
-        plan.local_corr if path is not None else plan.local_weights,
-        mesh=mesh,
-        in_specs=(in_spec,),
-        out_specs=P(),
-        check_vma=(strat.placement != "rowblock"),
-    )
-    if path is not None:
+    return _wire_runtime(mesh, strat, data_axis, model_axis, engine,
+                         glasso_steps, path)
+
+
+@functools.lru_cache(maxsize=None)
+def _wire_runtime(mesh: Mesh, strat: Strategy, data_axis: str,
+                  model_axis: str, engine: GramEngine | None,
+                  glasso_steps: int, path: PathPlan | None):
+    """The jitted runtime of :func:`build_weights_fn` and its input
+    sharding, built once per key (``experiments.clear_compile_caches``
+    drops them). A miss records one ``repro.wire.build`` span."""
+    with span("wire.build"):
+        plan = WirePlan(strat, data_axis=data_axis, model_axis=model_axis,
+                        engine=engine, glasso_steps=glasso_steps, path=path)
+        in_spec = P(data_axis, model_axis)
+        # check_vma=False, as on the trial plane's shard_maps: a Pallas
+        # kernel's output declares no varying mesh axes, which the check
+        # requires, and VMA inference cannot prove an all_gather's output
+        # replicated. The out spec is still honest: every rank holds the
+        # whole gathered payload, so the whole Gram (or, under rowblock,
+        # all its gathered row blocks).
+        inner = jax.shard_map(
+            plan.local_corr if path is not None else plan.local_weights,
+            mesh=mesh,
+            in_specs=(in_spec,),
+            out_specs=P(),
+            check_vma=False,
+        )
+        if path is None:
+            return jax.jit(inner), NamedSharding(mesh, in_spec)
+
         # the path engine's masked while_loop has no shard_map replication
         # rule; the shard_map ends at the (replicated, sharding-bit-stable)
         # correlation statistic and the fused grid scan + EBIC selection
@@ -580,8 +608,7 @@ def build_weights_fn(
                 n_steps=glasso_steps)
             return theta
 
-        return fused_path, NamedSharding(mesh, in_spec)
-    return inner, NamedSharding(mesh, in_spec)
+        return jax.jit(fused_path), NamedSharding(mesh, in_spec)
 
 
 def distributed_weights(
@@ -603,6 +630,12 @@ def distributed_weights(
     matrix, or the glasso precision matrix for a sparse strategy (the
     path-selected one under ``path=``).
 
+    The runtime comes from :func:`build_weights_fn`, built on the first
+    call for a mesh and strategy and reused after: a repeat call only
+    places ``x`` (one ``repro.wire.place`` span, free when ``x`` is
+    already placed so) and dispatches the runtime (one
+    ``repro.wire.weights`` span).
+
     Args:
       x: (n, d) samples; will be placed as P(data_axis, model_axis) — each
         device holds a (n/D, d/M) block, i.e. the paper's vertical partition.
@@ -614,10 +647,13 @@ def distributed_weights(
         mesh, strategy=strategy, method=method, rate=rate,
         data_axis=data_axis, model_axis=model_axis, compute=compute,
         wire=wire, engine=engine, glasso_steps=glasso_steps, path=path)
-    x = jax.device_put(x, sharding)
-    return jax.jit(fn)(x)
+    with span("wire.place"):
+        x = jax.device_put(x, sharding)
+    with span("wire.weights"):
+        return fn(x)
 
 
+@spanned("distributed_learn_structure")
 def distributed_learn_structure(
     x: jax.Array,
     mesh: Mesh,
@@ -640,9 +676,12 @@ def distributed_learn_structure(
 
     The MWST solver comes from ``backend`` if given, else
     ``strategy.mst``, else the on-device Boruvka default.
+
+    Each call is one ``repro.distributed_learn_structure`` span; a tree
+    strategy's MWST solve (its dispatch, for Boruvka) is one
+    ``repro.structure.mst`` span inside it, as in ``learn_structure``.
     """
     if strategy is not None and strategy.structure == "sparse":
-        from .chow_liu import adjacency_to_edges
         from .glasso import SUPPORT_TOL, support
 
         if backend is not None:
@@ -660,11 +699,10 @@ def distributed_learn_structure(
     if backend is None:
         backend = strategy.mst if strategy is not None else "boruvka"
     if backend == "boruvka":
-        from .chow_liu import adjacency_to_edges
-
         # device solve on the replicated weights; host conversion only at
         # the edge-list surface
-        return adjacency_to_edges(boruvka_mst(w))
-    from .chow_liu import kruskal_mst
-
-    return kruskal_mst(np.asarray(w))
+        with span("structure.mst"):
+            adj = boruvka_mst(w)
+        return adjacency_to_edges(adj)
+    with span("structure.mst"):
+        return kruskal_mst(np.asarray(w))

@@ -91,7 +91,7 @@ from . import estimators, glasso, sampler, trees
 from . import path as path_engine
 from .path import PathPlan
 from .chow_liu import boruvka_mst_batch, kruskal_mst
-from .distributed import CommReport, WirePlan
+from .distributed import CommReport, WirePlan, _wire_runtime
 from .faults import FaultPlan, fault_trial_keys
 from .gram import (GramConfig, GramEngine, default_memory_budget,
                    gram_working_set_bytes, resolve_engine)
@@ -1395,7 +1395,7 @@ def _compile_caches():
             _wire_point_fn, _sparse_plan_setup, _corr_stage,
             _sparse_metrics_fn, _sparse_path_metrics_fn,
             _sparse_sharded_corr_fn, _sparse_wire_corr_fn, _crossover_fn,
-            _corr_err_fn)
+            _corr_err_fn, _wire_runtime)
 
 
 def compile_cache_size() -> int:

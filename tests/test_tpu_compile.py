@@ -24,8 +24,9 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
     SingleDeviceSharding
 
-from repro.core import GramEngine
+from repro.core import GramEngine, Strategy
 from repro.core.chow_liu import boruvka_mst, boruvka_mst_batch
+from repro.core.distributed import build_weights_fn
 from repro.kernels.sign_corr import code_corr, sign_corr, sign_corr_packed
 from repro.serve import table
 
@@ -132,3 +133,22 @@ def test_tenant_sharded_fold_compiles_for_v5e(v5e, kind):
                                      sharding=NamedSharding(mesh,
                                                             PartitionSpec()))]
     assert "tpu_custom_call" in stage.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("placement", ["replicated", "rowblock"])
+def test_wire_runtime_compiles_for_v5e(v5e, placement):
+    """The four-machine deployment's runtime: (65536, 1024) samples whose
+    features lie over a (1, 4) mesh, each chip's packed sign payload
+    gathered over the mesh, the Pallas packed Gram inside the shard_map.
+    The jitted runtime keeps its module name."""
+    mesh = Mesh(np.array(v5e.devices).reshape(1, 4), ("data", "model"))
+    fn, sharding = build_weights_fn(
+        mesh, strategy=Strategy("sign", wire="packed", placement=placement),
+        engine=GramEngine(backend="pallas", interpret=False))
+    lowered = fn.lower(jax.ShapeDtypeStruct((65536, 1024), jnp.float32,
+                                            sharding=sharding))
+    assert re.search(r"^module @(\S+)", lowered.as_text(),
+                     re.M).group(1) == "jit_local_weights"
+    text = lowered.compile().as_text()
+    assert _kernel_names(text) == {"sign_corr_packed"}
+    assert re.search(r"\ball-gather(-start)?\(", text)
