@@ -34,10 +34,10 @@ def vmem_working_set() -> dict:
     quant = bm * bnq * 4 + bm * bnq * 1 + bm * bnq * 4 + (127 + 128) * 4
     g, dh, bs = 8, 128, 512
     dec = g * dh * 4 + 2 * bs * dh * 4 + g * bs * 4 + g * dh * 4 + 2 * g * 4
-    # packed popcount: two (bd, bw) int32 word tiles in, one (8, bd, bw)
-    # int32 XOR intermediate (8 output rows per step), int32 accumulator out
-    pbd, pbw = 128, 128
-    packed = 2 * pbd * pbw * 4 + 8 * pbd * pbw * 4 + pbd * pbd * 4
+    # packed bit planes: two (bd, bb) byte tiles in, two (bd, bb) int8
+    # planes and one (bd, bd) int32 dot result live, int32 accumulator out
+    pbd, pbb = 512, 1024
+    packed = 2 * pbd * pbb + 2 * pbd * pbb + 2 * pbd * pbd * 4
     return {"sign_corr": sign, "sign_corr_packed": packed, "quantize": quant,
             "decode_attention": dec, "vmem_budget": 16 * 2**20}
 

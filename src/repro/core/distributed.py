@@ -44,7 +44,7 @@ telemetry and bit-identical metrics to the single-device engine.
 
 Every Gram goes through :class:`repro.core.gram.GramEngine` (Pallas kernels
 on TPU, XLA matmuls on CPU). For ``wire="packed"`` with the sign method
-the Gram is computed **directly on the packed payload** via XNOR+popcount
+the Gram is computed **directly on the packed payload**
 (G = n - 2*popcount(xor)) — the gathered wire bytes are the kernel operand,
 nothing is unpacked back to int8/f32. For int8 wires, codes enter the kernel
 as int8 (sign upcast / centroid decode fused per tile).

@@ -49,7 +49,7 @@ def theta_hat_packed(
 ) -> jax.Array:
     """theta_hat (eq. 8) straight from the 1-bit packed wire payload —
     (d, ceil(n/8)) uint8, ``quantizers.pack_codes`` layout — via the
-    XNOR+popcount Gram. Exact: equals :func:`theta_hat` on the unpacked u."""
+    packed sign Gram. Exact: equals :func:`theta_hat` on the unpacked u."""
     gram = resolve_engine(engine).packed_sign_gram(packed, n)
     return 0.5 + gram / (2.0 * n)
 
@@ -734,7 +734,7 @@ def payload_gram(
     Dispatches through the engine's batched entry points when the payload
     carries a leading batch axis (the trial plane's trial dimension — one
     kernel launch for the whole batch on pallas). 1-bit packed sign
-    payloads are contracted DIRECTLY (XNOR + popcount on the wire bytes);
+    payloads are contracted DIRECTLY (the packed sign Gram on the wire bytes);
     everything else goes through :func:`payload_operand` first.
 
     ``payload_rows`` (a feature-slice payload of the same format) selects

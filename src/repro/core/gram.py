@@ -29,7 +29,7 @@ Three input kinds cover every wire format; HBM/wire bytes per symbol:
   f32 values    ``gram(x)``            MXU bf16 / f32 matmul       4
   int8 values   ``gram(u)``            in-tile int8->bf16 matmul   1
   int8 codes    ``code_gram(c, cb)``   in-kernel centroid decode   1
-  packed bits   ``packed_sign_gram``   XNOR + popcount             1/8
+  packed bits   ``packed_sign_gram``   VMEM bit planes, int8 MXU   1/8
   ============  =====================  ==========================  =========
 
 (the xla/numpy backends match each entry point's semantics but may widen
@@ -119,7 +119,7 @@ class GramConfig:
 
     block_n: int = 512
     block_d: int = 256
-    block_b: int = 512
+    block_b: int = 1024
     d_tile: int | None = None
     n_chunk: int | None = None
 
@@ -207,9 +207,10 @@ class GramEngine:
         not running on a TPU (so ``backend="pallas"`` is always safe in
         tests).
       block_n / block_d / block_b: kernel tile sizes for the pallas backend.
-        ``block_d`` is clamped to 128 for the code/packed kernels (their
-        per-tile VMEM working sets — decoded f32 tiles and the XOR
-        intermediate — grow with block_d).
+        ``block_d`` is clamped to 128 for the code kernel (its decoded f32
+        tiles grow with block_d) and is not used by the packed kernel,
+        whose output tile is its own default (``sign_corr_packed``);
+        ``block_b`` is the packed kernel's bytes per n-step.
       d_tile: stream the (d, d) output in (d_tile, d_tile) blocks when d
         exceeds it (``None`` = monolithic). Bit-identical for integer-exact
         paths; bounds every backend's transient working set.
@@ -229,7 +230,7 @@ class GramEngine:
     interpret: bool | None = None
     block_n: int = 512
     block_d: int = 256
-    block_b: int = 512
+    block_b: int = 1024
     d_tile: int | None = None
     n_chunk: int | None = None
     autotune: bool = False
@@ -485,8 +486,7 @@ class GramEngine:
                       backend: str, batched: bool):
         if backend == "pallas":
             return sign_corr_packed(
-                packed, n, rhs,
-                block_d=min(cfg.block_d, 128), block_b=cfg.block_b,
+                packed, n, rhs, block_b=cfg.block_b,
                 interpret=self._interpret())
         nb = packed.shape[-1]
         chunk_b = nb if cfg.n_chunk is None else max(
@@ -711,10 +711,9 @@ def candidate_configs(
     """
     cands = [GramConfig()]
     if backend == "pallas":
-        if path == "packed":
-            for bd in (64, 128):
-                for bb in (512, 1024):
-                    cands.append(GramConfig(block_d=bd, block_b=bb))
+        if path == "packed":  # the kernel picks its own output tile
+            for bb in (512, 2048):
+                cands.append(GramConfig(block_b=bb))
         elif path == "code":
             for bn in (256, 512, 1024):
                 cands.append(GramConfig(block_n=bn, block_d=128))

@@ -195,7 +195,7 @@ class Strategy:
 
     def packed_gram_ok(self, n: int) -> bool:
         """True when the dense packed payload of ``n`` samples can feed the
-        Gram engine directly (XNOR+popcount, no unpack): sign method,
+        Gram engine directly (no unpack in HBM): sign method,
         packed wire, and n a multiple of the 8-symbol byte granularity.
         The estimators fall back to the (statistically identical) int8
         contraction otherwise — shape buckets are powers of two precisely

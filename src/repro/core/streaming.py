@@ -15,7 +15,7 @@ Every per-batch Gram goes through :class:`repro.core.gram.GramEngine`:
 * :meth:`update_codes` folds in already-quantized wire blocks directly
   (what the center actually receives);
 * :meth:`update_packed` folds in 1-bit packed sign payloads via the
-  XNOR+popcount Gram — the wire bytes are the compute operand;
+  packed sign Gram — the wire bytes are the compute operand;
 * :meth:`update_codes_batch` / :meth:`update_packed_batch` fold a STACK of
   per-machine blocks (the shard-ingestion case: M machines' payloads
   arriving together) through the engine's batched kernel grids

@@ -2,9 +2,10 @@
 
 * sign_corr        — quantized-code Gram contraction (paper eq. 8 / eq. 32);
                      rectangular u^T v supported for rowblock placements
-* sign_corr_packed — sign Gram straight from 1-bit packed codes via
-                     XNOR + popcount (G = n - 2*popcount(xor)); the wire
-                     payload is the compute payload, 1 bit/symbol HBM traffic
+* sign_corr_packed — sign Gram straight from 1-bit packed codes: the ±1
+                     bit planes are unpacked in VMEM and contracted on the
+                     int8 MXU; the wire payload is the compute payload,
+                     1 bit/symbol HBM traffic
 * code_corr        — per-symbol Gram from int8 bin codes with the centroid
                      decode fused in-kernel (no f32 decode in HBM)
 * quantize         — fused per-symbol R-bit encode + centroid decode (eq. 40),

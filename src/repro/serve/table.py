@@ -93,8 +93,8 @@ def _codes_fold_stage(slots: int, block_n: int, d: int, method: str,
 def _packed_fold_stage(slots: int, block_n: int, d: int,
                        engine: GramEngine, mesh=None):
     """jit: (slots, d, block_n/8) uint8 + (slots,) valid counts ->
-    (slots, d, d) f32. Zero-padded tail bits xor to agreement under the
-    XNOR+popcount kernel; the integer-exact uniform shift
+    (slots, d, d) f32. Zero-padded tail bits count as agreement under the
+    packed sign kernel; the integer-exact uniform shift
     ``G_i = n_valid[i] - 2*popcount`` restores the true prefix Gram (the
     same identity as ``StreamingGram.update_packed_batch``) — an all-zero
     padding slot lands exactly on 0.

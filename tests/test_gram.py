@@ -48,6 +48,7 @@ def _pack(u):
     (300, 257),    # d past two tiles
     (1000, 7),     # byte-ragged n (1000 = 125 bytes exactly), skinny d
     (513, 64),     # n one past a block multiple
+    (8192, 31),    # n one whole default n-step (1024 bytes): no pad bits
 ])
 def test_sign_corr_packed_parity_odd_shapes(n, d):
     u = _signs(n, d, seed=n * 1000 + d)
@@ -67,6 +68,70 @@ def test_sign_corr_packed_block_sweep(bd, bb):
     want = u.astype(np.float64).T @ u.astype(np.float64)
     got = sign_corr_packed(_pack(u), n, block_d=bd, block_b=bb, interpret=True)
     assert np.array_equal(np.asarray(got), want)
+
+
+def _packed_refs(packed, n, rhs=None):
+    """The two exact references: the numpy engine (n - 2*popcount(xor))
+    and the unpack-then-contract oracle (pad bits masked to 0)."""
+    from repro.kernels.ref import sign_corr_packed_ref
+
+    r = packed if rhs is None else rhs
+    got_np = NUMPY.packed_sign_gram(np.asarray(packed), n, np.asarray(r))
+    got_ref = np.asarray(sign_corr_packed_ref(packed, n, r))
+    assert np.array_equal(got_np, got_ref)
+    return got_np
+
+
+@pytest.mark.parametrize("bd,bb", [
+    (512, 1024),   # the defaults: one 384-wide tile, two n-steps
+    (128, 512),    # 3x3 output tiles, three n-steps
+    (256, 128),    # 2x2 tiles, nine n-steps
+])
+def test_sign_corr_packed_tiles_at_width(bd, bb):
+    """The bit-plane body across output tiles and n-steps at a width past
+    one tile: n = 8203 leaves five pad bits in the last byte and pads the
+    bytes to whole n-steps."""
+    n, d = 8203, 300
+    packed = _pack(_signs(n, d, seed=bd + bb))
+    got = np.asarray(sign_corr_packed(packed, n, block_d=bd, block_b=bb,
+                                      interpret=True))
+    assert np.array_equal(got, _packed_refs(packed, n))
+
+
+def _shared_pad_payload():
+    """Zero tail bits replaced by set bits shared by every row: each still
+    adds +1 to every entry, which the wrapper subtracts."""
+    n, d = 203, 45
+    p = np.asarray(_pack(_signs(n, d, seed=5))).copy()
+    p[:, -1] |= np.uint8((0xFF << (n % 8)) & 0xFF)
+    return n, jnp.asarray(p), None
+
+
+def _batched_payload():
+    rng = np.random.default_rng(12)
+    n, d, b = 1000, 37, 3
+    u = rng.choice([-1, 1], size=(b, n, d)).astype(np.int8)
+    return n, jnp.stack([_pack(u[i]) for i in range(b)]), None
+
+
+def _rectangular_payload():
+    n, dl, dr = 1000, 130, 37  # a 256-row tile beside a 64-lane one
+    u = _signs(n, dl + dr, seed=13)
+    return n, _pack(u[:, :dl]), _pack(u[:, dl:])
+
+
+@pytest.mark.parametrize("make", [
+    _shared_pad_payload, _batched_payload, _rectangular_payload,
+], ids=["shared_pad_bits", "batched", "rectangular"])
+def test_sign_corr_packed_matches_references(make):
+    n, packed, rhs = make()
+    got = np.asarray(sign_corr_packed(packed, n, rhs, interpret=True))
+    if packed.ndim == 3:
+        want = np.stack([_packed_refs(packed[i], n)
+                         for i in range(packed.shape[0])])
+    else:
+        want = _packed_refs(packed, n, rhs)
+    assert np.array_equal(got, want)
 
 
 def test_sign_corr_packed_rectangular():

@@ -88,8 +88,10 @@ def test_code_corr_compiles_for_v5e(one_chip, shape):
 
 
 @pytest.mark.parametrize("shape,n", [((60, 20, 512), 4096),
-                                     ((4096, 8192), 65536)])
+                                     ((4096, 8192), 65536),
+                                     ((1024, 8192), 65536)])
 def test_sign_corr_packed_compiles_for_v5e(one_chip, shape, n):
+    # (1024, 8192): the structure cells' payload at the default tiles
     # compiled inside another jitted function: the name stays the kernel's
     text = _compile_text(lambda p: sign_corr_packed(p, n), one_chip,
                          (shape, jnp.uint8))
