@@ -335,7 +335,7 @@ def custom_call_check(packed, codes, centroids, n) -> None:
 # phase 3: the serving plane
 # ---------------------------------------------------------------------------
 
-#: the throughput phase of benchmarks/serve.py at full size
+#: 64 tenants of 4 machines each, sign payloads, light wire pathologies
 TRAFFIC = dict(tenants=64, machines=4, ticks=20, n=48, d=32,
                p_duplicate=0.05, p_reorder=0.05, p_drop=0.02, seed=3)
 SERVE = dict(tenants=64, machines=4, d=32, block_n=48, snapshot_every=4,

@@ -27,14 +27,8 @@ def run(reps: int = 120, quick: bool = False) -> dict:
     for key, errs in table.items():
         print(f"fig3 {key:<9} " + " ".join(f"{e:.3f}" for e in errs),
               flush=True)
-    print(f"fig3 engine: {plan.trials} trials in {res.seconds:.2f}s "
-          f"({res.trials_per_s:.0f} trials/s, {res.host_syncs} host syncs)",
-          flush=True)
     payload = {"d": D, "ns": list(ns), "reps": reps, "error": table,
-               "edit_distance": res.edit_distance,
-               "engine": {"seconds": res.seconds,
-                          "trials_per_s": res.trials_per_s,
-                          "host_syncs": res.host_syncs}}
+               "edit_distance": res.edit_distance}
     # paper-claim checks (soft, recorded in the artifact):
     checks = {
         "sign_beats_ps1": all(

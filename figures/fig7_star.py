@@ -52,9 +52,7 @@ def run(reps: int = 200, quick: bool = False) -> dict:
                "comm": [{"n": n, "logical_bits": c.logical_bits,
                          "wire_bits": 8 * c.wire_bytes,
                          "wire_bits_packed": strat_packed_bits(n)}
-                        for n, c in zip(ns, comm)],
-               "engine": {"seconds": res.seconds,
-                          "trials_per_s": res.trials_per_s}}
+                        for n, c in zip(ns, comm)]}
     save_artifact("fig7_star", payload)
     return payload
 

@@ -1,4 +1,4 @@
-"""Distributed-GGM communication benchmark (the paper's own cost table).
+"""Distributed-GGM communication cost (the paper's own cost table).
 
 For the production GGM config (d features over the model axis) this
 reports the bits crossing the links per method/rate — the quantity the

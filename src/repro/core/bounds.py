@@ -1,7 +1,7 @@
 """Closed-form error bounds from the paper (Lemmas 3-4, Theorems 1-2, eq. 43).
 
 Everything here is plain numpy on scalars/small arrays — these are analysis
-formulas plotted against the empirical benchmarks, not device code.
+formulas plotted against the empirical figures, not device code.
 """
 from __future__ import annotations
 

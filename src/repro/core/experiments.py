@@ -2,9 +2,10 @@
 
 The paper's results are all Monte-Carlo estimates — Pr(T_hat != T) over
 hundreds of (tree, data, method, R, n) trials (Figs. 3-11). The reference
-loop (``benchmarks.common.recovery_error_rate``) executes one trial at a
-time through Python with a host numpy round-trip per trial. This module
-replaces it with a batched engine built from three stacked optimizations:
+loop (``recovery_error_rate`` in ``tests/test_experiments.py``) executes
+one trial at a time through Python with a host numpy round-trip per
+trial. This module replaces it with a batched engine built from three
+stacked optimizations:
 
 * **Shape bucketing** — each sample size n is padded up to a small set of
   buckets (powers of two by default; ``TrialPlan.n_buckets`` overrides)
@@ -112,7 +113,7 @@ def next_pow2(n: int) -> int:
 
 def _gram_path(s: Strategy) -> str:
     """Which GramEngine path a strategy's payload contracts through
-    (the key of ``gram.gram_working_set_bytes`` / the autotune layer)."""
+    (the key of ``gram.gram_working_set_bytes``)."""
     if s.method == "original":
         return "f32"
     if s.method == "sign":
@@ -316,7 +317,7 @@ class TrialPlan:
                                        config=cfg, batch=self.reps)
                 for p in paths)
 
-        if worst(engine._base_config()) <= budget:
+        if worst(engine._config()) <= budget:
             return engine
         for t in (1024, 512, 256, 128):
             if t >= self.d:
@@ -1725,16 +1726,10 @@ def run_trials(
     if mst not in ("device", "host_kruskal"):
         raise ValueError(f"unknown mst mode {mst!r}")
     # memory budget: clamp the engine's streaming knobs to the plan
-    # (deterministic per (plan, engine) — mesh-parity-safe), pick the
-    # solve-stage slab, and pre-tune autotuning engines EAGERLY (sweeps
-    # cannot run under the jit traces below, only cached winners apply)
+    # (deterministic per (plan, engine) — mesh-parity-safe) and pick the
+    # solve-stage slab
     engine = plan.budget_engine(engine)
     chunk = plan.metrics_chunk()
-    if engine.autotune:
-        for b in sorted({plan.bucket_for(n) for n in plan.ns}):
-            for path in sorted({_gram_path(s) for s in plan.strategies}):
-                engine.tune(path, b, plan.d,
-                            budget=plan.effective_memory_budget // 2)
     sparse = plan.structure == "sparse"
     if mst == "host_kruskal":
         if mesh is not None:
@@ -1924,10 +1919,10 @@ def run_trials(
                     fault_sums.append(out[1])
     # (S, len(ns), 3) metric tensor, still on device; THE host sync.
     # host_syncs counts actual read-backs (the += convention every host
-    # touch in this loop must follow), so the one_sync_per_sweep checks in
-    # CI and benchmarks/trials.py stay real canaries — a future per-point
-    # device_get sneaking back in shows up as host_syncs > 1. The fault
-    # telemetry stacks ride the SAME read-back.
+    # touch in this loop must follow), so the tests' host_syncs == 1
+    # checks stay real canaries — a future per-point device_get sneaking
+    # back in shows up as host_syncs > 1. The fault telemetry stacks ride
+    # the SAME read-back.
     syncs = 0
     if path_mode:
         # the selected-support sums are the headline channels; the full

@@ -65,17 +65,10 @@ orthogonal engine knobs bound them:
   so chunked accumulation is also bit-identical. Float values are never
   n-chunked (that would change the reduction order of the baseline).
 
-``autotune=True`` picks (block_n, block_d, block_b, d_tile, n_chunk) per
-(backend, path, shape-bucket, platform) by timing the candidate set in
-:func:`candidate_configs` on first use. Winners persist to a JSON cache
-(``REPRO_GRAM_AUTOTUNE_CACHE``, default ``~/.cache/repro/gram_autotune.json``,
-keyed by platform and device kind so one file serves heterogeneous fleets);
-warm processes skip the sweep. A candidate that fails to compile is skipped
-with a message on stderr; a sweep in which every candidate fails raises.
-``REPRO_GRAM_AUTOTUNE=0`` disables sweeping entirely.
-Sweeps only ever run eagerly: inside a jit trace the engine falls back to
-the cached winner or the engine's own config — pre-tune with
-:meth:`GramEngine.tune` (``run_trials`` does) before tracing hot loops.
+The kernel tile edges (``block_n``, ``block_d``, ``block_b``) are fixed
+defaults; the packed kernel's come from a tile sweep on a TPU v5e
+(PERF.md). ``TrialPlan.budget_engine`` sets ``d_tile``/``n_chunk`` from
+the memory budget it observes.
 
 :func:`gram_working_set_bytes` is the shared analytic model of those
 transients; ``TrialPlan`` uses it (via :func:`default_memory_budget`) to
@@ -85,10 +78,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import json
 import os
-import sys
-import time
 from typing import Literal
 
 import numpy as np
@@ -99,10 +89,6 @@ from repro.kernels.sign_corr import code_corr, sign_corr, sign_corr_packed
 
 Backend = Literal["auto", "pallas", "xla", "numpy"]
 
-#: Env var: set to "0" to disable autotune sweeps (cached winners still load).
-AUTOTUNE_ENV = "REPRO_GRAM_AUTOTUNE"
-#: Env var: path of the persistent autotune JSON cache.
-AUTOTUNE_CACHE_ENV = "REPRO_GRAM_AUTOTUNE_CACHE"
 #: Env var: override the backend-derived memory budget (bytes).
 MEMORY_BUDGET_ENV = "REPRO_MEMORY_BUDGET_BYTES"
 
@@ -123,9 +109,6 @@ class GramConfig:
     d_tile: int | None = None
     n_chunk: int | None = None
 
-    def as_dict(self) -> dict:
-        return dataclasses.asdict(self)
-
 
 def _spans(size: int, tile: int) -> list[tuple[int, int]]:
     return [(i, min(i + tile, size)) for i in range(0, size, tile)]
@@ -138,11 +121,6 @@ def _assemble_tiles(block_fn, dl: int, dr: int, tile: int, xp):
         row = [block_fn(i0, i1, j0, j1) for j0, j1 in _spans(dr, tile)]
         rows.append(row[0] if len(row) == 1 else xp.concatenate(row, axis=-1))
     return rows[0] if len(rows) == 1 else xp.concatenate(rows, axis=-2)
-
-
-def _concrete(*arrays) -> bool:
-    return not any(
-        isinstance(a, jax.core.Tracer) for a in arrays if a is not None)
 
 
 def _binary_antisymmetric_centroid(centroids) -> float | None:
@@ -218,9 +196,6 @@ class GramEngine:
         samples (packed: ``n_chunk/8``-byte chunks). ``None`` = one pass.
         Never applied to float values (reduction-order stability of the
         unquantized baseline).
-      autotune: look up / sweep a tuned :class:`GramConfig` per (path,
-        shape bucket) on first eager use, overriding the block/tile fields
-        above. See the module docstring for cache and escape-hatch env vars.
 
     The dataclass stays frozen/hashable: engine instances key the jitted
     stage caches in ``core.experiments``.
@@ -233,7 +208,6 @@ class GramEngine:
     block_b: int = 1024
     d_tile: int | None = None
     n_chunk: int | None = None
-    autotune: bool = False
 
     def resolve(self) -> str:
         b = self.backend
@@ -249,29 +223,12 @@ class GramEngine:
             return jax.default_backend() != "tpu"
         return self.interpret
 
-    def _base_config(self) -> GramConfig:
+    def _config(self) -> GramConfig:
         return GramConfig(self.block_n, self.block_d, self.block_b,
                           self.d_tile, self.n_chunk)
 
     def _xp(self, backend: str):
         return np if backend == "numpy" else jnp
-
-    def _config(self, path: str, n: int, d: int, *, concrete: bool
-                ) -> GramConfig:
-        base = self._base_config()
-        if not self.autotune:
-            return base
-        # inside a jit trace, never sweep (timing under tracing is
-        # meaningless): cached winners still apply, else the engine config
-        return tuned_config(path, n, d, self, default=base, sweep=concrete)
-
-    def tune(self, path: str, n: int, d: int, *,
-             budget: int | None = None) -> GramConfig:
-        """Eagerly resolve (sweeping on first use) the tuned config for one
-        (path, shape) point; ``budget`` restricts candidates to configs whose
-        :func:`gram_working_set_bytes` fits. path: f32|int8|code|packed."""
-        return tuned_config(path, n, d, self, default=self._base_config(),
-                            budget=budget)
 
     # -- values: f32 / bf16 / int8 ±1 or centroid values --------------------
 
@@ -300,10 +257,8 @@ class GramEngine:
             jnp.issubdtype(a.dtype, jnp.integer) or a.dtype == jnp.bfloat16
             for a in ops)
         exact_int = all(jnp.issubdtype(a.dtype, jnp.integer) for a in ops)
-        n, dl = u.shape[-2], u.shape[-1]
-        dr = ops[-1].shape[-1]
-        cfg = self._config("int8" if exact_bf16 else "f32", n, max(dl, dr),
-                           concrete=_concrete(*ops))
+        dl, dr = u.shape[-1], ops[-1].shape[-1]
+        cfg = self._config()
         block = functools.partial(
             self._value_block, cfg=cfg, backend=backend, batched=batched,
             exact_bf16=exact_bf16, exact_int=exact_int)
@@ -383,10 +338,9 @@ class GramEngine:
             v = None if rhs is None else _binary_codes_to_signs(rhs, xp)
             scale = np.float32(c) * np.float32(c)  # one f32 rounding
             return self._value_gram(u, v, batched=batched) * scale
-        n, dl = codes.shape[-2], codes.shape[-1]
+        dl = codes.shape[-1]
         dr = dl if rhs is None else rhs.shape[-1]
-        cfg = self._config("code", n, max(dl, dr),
-                           concrete=_concrete(codes, rhs))
+        cfg = self._config()
         t = cfg.d_tile
         if t is not None and t < max(dl, dr):
             rr = codes if rhs is None else rhs
@@ -470,8 +424,7 @@ class GramEngine:
         backend = self.resolve()
         dl = packed.shape[-2]
         dr = dl if rhs is None else rhs.shape[-2]
-        cfg = self._config("packed", n, max(dl, dr),
-                           concrete=_concrete(packed, rhs))
+        cfg = self._config()
         t = cfg.d_tile
         if t is not None and t < max(dl, dr):
             rr = packed if rhs is None else rhs
@@ -602,245 +555,6 @@ def default_memory_budget() -> int:
             f"{dev.device_kind} reports no bytes_limit in memory_stats(); "
             f"set {MEMORY_BUDGET_ENV} to its HBM size in bytes")
     return 8 << 30
-
-
-# ---------------------------------------------------------------------------
-# Autotune layer: per-(platform, backend, path, shape bucket) tile sweeps
-# ---------------------------------------------------------------------------
-
-_tuned: dict[str, GramConfig] = {}
-_cache_loaded_from: str | None = None
-_sweep_count = 0
-
-
-def autotune_enabled() -> bool:
-    return os.environ.get(AUTOTUNE_ENV, "1") != "0"
-
-
-def autotune_cache_path() -> str:
-    return os.environ.get(AUTOTUNE_CACHE_ENV) or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro", "gram_autotune.json")
-
-
-def autotune_sweep_count() -> int:
-    """Number of timing sweeps run by this process (test/CI hook: a warm
-    cache — in-memory or JSON — must keep this flat across repeat calls)."""
-    return _sweep_count
-
-
-def clear_autotune_cache(*, remove_file: bool = False) -> None:
-    """Drop in-memory tuned configs (and optionally the JSON cache file).
-
-    The sweep counter is NOT reset: tests diff it around calls.
-    """
-    global _cache_loaded_from
-    _tuned.clear()
-    _cache_loaded_from = None
-    if remove_file:
-        try:
-            os.remove(autotune_cache_path())
-        except OSError:
-            pass
-
-
-def _pow2_bucket(x: int) -> int:
-    b = 8
-    while b < x:
-        b <<= 1
-    return b
-
-
-def _tune_key(path: str, n: int, d: int, backend: str) -> str:
-    kind = jax.devices()[0].device_kind
-    return (f"{jax.default_backend()}:{kind}:{backend}:{path}"
-            f":n{_pow2_bucket(n)}:d{_pow2_bucket(d)}")
-
-
-def _load_cache_file() -> None:
-    global _cache_loaded_from
-    path = autotune_cache_path()
-    if _cache_loaded_from == path:
-        return
-    _cache_loaded_from = path
-    try:
-        with open(path) as f:
-            data = json.load(f)
-        for key, fields in data.get("entries", {}).items():
-            _tuned.setdefault(key, GramConfig(**fields))
-    except (OSError, ValueError, TypeError):
-        pass  # absent or corrupt cache: resweep
-
-
-def _store_cache_file() -> None:
-    path = autotune_cache_path()
-    try:
-        entries = {}
-        try:  # merge-on-write: keep other processes' winners
-            with open(path) as f:
-                entries = json.load(f).get("entries", {})
-        except (OSError, ValueError):
-            pass
-        entries.update({k: c.as_dict() for k, c in _tuned.items()})
-        parent = os.path.dirname(path)
-        if parent:
-            os.makedirs(parent, exist_ok=True)
-        tmp = f"{path}.tmp.{os.getpid()}"
-        with open(tmp, "w") as f:
-            json.dump({"version": 1, "entries": dict(sorted(entries.items()))},
-                      f, indent=1, sort_keys=True)
-        os.replace(tmp, path)
-    except OSError:
-        pass  # read-only FS: in-memory cache still serves this process
-
-
-def candidate_configs(
-    path: str,
-    n: int,
-    d: int,
-    backend: str = "xla",
-    *,
-    budget: int | None = None,
-) -> list[GramConfig]:
-    """Autotune candidate set for one (path, shape, backend) point.
-
-    The first entry is always the engine-default config (the sweep can only
-    improve on the status quo). Pallas candidates vary kernel tile edges;
-    xla/numpy candidates vary the engine-level d_tile / n_chunk streaming.
-    ``budget`` drops candidates whose :func:`gram_working_set_bytes` exceeds
-    it (keeping the thriftiest one if none fit).
-    """
-    cands = [GramConfig()]
-    if backend == "pallas":
-        if path == "packed":  # the kernel picks its own output tile
-            for bb in (512, 2048):
-                cands.append(GramConfig(block_b=bb))
-        elif path == "code":
-            for bn in (256, 512, 1024):
-                cands.append(GramConfig(block_n=bn, block_d=128))
-        else:
-            for bn in (256, 512, 1024):
-                for bd in (128, 256):
-                    cands.append(GramConfig(block_n=bn, block_d=bd))
-    else:
-        d_tiles = [t for t in (128, 256, 512, 1024) if t < d]
-        for t in d_tiles:
-            cands.append(GramConfig(d_tile=t))
-        if path in ("int8", "packed") and n > 4096:
-            for t in d_tiles or [d]:
-                cands.append(GramConfig(
-                    d_tile=None if t == d else t, n_chunk=4096))
-    seen, uniq = set(), []
-    for c in cands:
-        if c not in seen:
-            seen.add(c)
-            uniq.append(c)
-    if budget is not None:
-        fits = [c for c in uniq
-                if gram_working_set_bytes(
-                    path, n, d, backend=backend, config=c) <= budget]
-        uniq = fits or [min(uniq, key=lambda c: gram_working_set_bytes(
-            path, n, d, backend=backend, config=c))]
-    return uniq
-
-
-def _sweep_operands(path: str, n: int, d: int, backend: str) -> tuple:
-    if path == "packed":
-        ops = (np.zeros((d, max(1, -(-n // 8))), np.uint8),)
-    elif path == "code":
-        ops = (np.zeros((n, d), np.int8),
-               np.linspace(-1.0, 1.0, 8, dtype=np.float32))
-    elif path == "int8":
-        ops = (np.ones((n, d), np.int8),)
-    else:
-        ops = (np.ones((n, d), np.float32),)
-    if backend == "numpy":
-        return ops
-    return tuple(jnp.asarray(o) for o in ops)
-
-
-def _block_until_ready(x) -> None:
-    if hasattr(x, "block_until_ready"):
-        x.block_until_ready()
-
-
-def _time_config(engine: GramEngine, cfg: GramConfig, path: str,
-                 ops: tuple, n: int) -> float:
-    eng = dataclasses.replace(
-        engine, autotune=False, block_n=cfg.block_n, block_d=cfg.block_d,
-        block_b=cfg.block_b, d_tile=cfg.d_tile, n_chunk=cfg.n_chunk)
-    if path == "packed":
-        fn = lambda: eng.packed_sign_gram(ops[0], n)  # noqa: E731
-    elif path == "code":
-        fn = lambda: eng.code_gram(ops[0], ops[1])  # noqa: E731
-    else:
-        fn = lambda: eng.gram(ops[0])  # noqa: E731
-    _block_until_ready(fn())  # compile + warm
-    best = float("inf")
-    for _ in range(2):
-        t0 = time.perf_counter()
-        _block_until_ready(fn())
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def tuned_config(
-    path: str,
-    n: int,
-    d: int,
-    engine: GramEngine,
-    *,
-    default: GramConfig | None = None,
-    sweep: bool = True,
-    budget: int | None = None,
-) -> GramConfig:
-    """Cached tuned config for (platform, device kind, backend, path,
-    shape bucket).
-
-    Resolution order: in-memory cache -> JSON cache file -> (if ``sweep``
-    and the ``REPRO_GRAM_AUTOTUNE`` hatch is open) a timing sweep over
-    :func:`candidate_configs` at the bucketed shape, persisted for future
-    processes. With sweeping unavailable, returns ``default`` (the engine's
-    own config). A candidate that raises is reported on stderr and
-    skipped; if every candidate raises, so does the sweep.
-    """
-    global _sweep_count
-    default = default or engine._base_config()
-    if not autotune_enabled():
-        return default
-    backend = engine.resolve()
-    key = _tune_key(path, n, d, backend)
-    hit = _tuned.get(key)
-    if hit is None:
-        _load_cache_file()
-        hit = _tuned.get(key)
-    if hit is not None:
-        return hit
-    if not sweep:
-        return default
-    nb, db = _pow2_bucket(n), _pow2_bucket(d)
-    nb = min(nb, 4096)  # cap sweep cost; tiles transfer across n buckets
-    _sweep_count += 1
-    ops = _sweep_operands(path, nb, db, backend)
-    best_cfg, best_t = None, float("inf")
-    errors = []
-    for cfg in candidate_configs(path, nb, db, backend, budget=budget):
-        try:
-            t = _time_config(engine, cfg, path, ops, nb)
-        except Exception as e:  # noqa: BLE001 — reported, then skipped
-            errors.append(f"{cfg}: {type(e).__name__}: {e}")
-            print(f"gram autotune [{key}] skipped {cfg}: "
-                  f"{type(e).__name__}: {str(e).splitlines()[0][:200]}",
-                  file=sys.stderr, flush=True)
-            continue
-        if t < best_t:
-            best_cfg, best_t = cfg, t
-    if best_cfg is None:
-        raise RuntimeError(
-            f"gram autotune [{key}]: every candidate failed:\n"
-            + "\n".join(errors))
-    _tuned[key] = best_cfg
-    _store_cache_file()
-    return best_cfg
 
 
 # ---------------------------------------------------------------------------

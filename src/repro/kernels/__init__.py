@@ -16,7 +16,6 @@
 ``repro.core.gram.GramEngine`` is the dispatch layer that routes every Gram
 in the repo (estimators / streaming / distributed) onto these kernels.
 
-Each kernel has a pure-jnp oracle in ref.py; ops.py exposes jit'd wrappers
-that interpret on CPU and compile natively on TPU.
+Each kernel has a pure-jnp oracle in ref.py.
 """
-from . import ops, ref  # noqa: F401
+from . import ref  # noqa: F401

@@ -55,9 +55,8 @@ Every kernel is TILED over (d_tile, d_tile) OUTPUT blocks with an n-step
 accumulation loop as the trailing grid dimension, so per-program VMEM is
 bounded by the block shape — never by n or d. What the grid does NOT
 bound is the padded HBM footprint: small d pads up to the output-tile
-edge. The pad target is picked from :data:`PAD_TILES` (the small end of
-the ``core.gram`` autotune candidate set) — the smallest candidate >= d —
-instead of a blind 128-multiple: at d=20 the operands pad to 32 lanes
+edge. The pad target is picked from :data:`PAD_TILES` — the smallest
+candidate >= d — instead of a blind 128-multiple: at d=20 the operands pad to 32 lanes
 (1.6x), not 128 (>6x wasted lanes). Padded results are bit-identical to
 exact shapes (pad rows/lanes contribute exact zeros, or for packed codes
 a known count that the wrapper subtracts), pinned by the odd-d
@@ -88,8 +87,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-#: Output-tile pad candidates for the MXU kernels, shared with the
-#: ``core.gram`` autotune layer's d_tile candidate set. Small d pads to the
+#: Output-tile pad candidates for the MXU kernels. Small d pads to the
 #: smallest candidate that covers it instead of a blind 128-multiple.
 PAD_TILES = (32, 64, 128)
 

@@ -12,7 +12,7 @@ For each combo this records, into benchmarks/artifacts/dryrun/:
   * collective_bytes   — sum of per-device payload bytes over every
     all-gather / all-reduce / reduce-scatter / all-to-all /
     collective-permute in the post-SPMD optimized HLO,
-  * the roofline terms derived from the above (see benchmarks/roofline.py).
+  * the roofline terms derived from the above.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch granite-8b --shape train_4k

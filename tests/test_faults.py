@@ -415,3 +415,45 @@ def test_fault_sweep_shares_draws_across_strategies():
     assert rs.faults is not None and rs.host_syncs == 1
     for lab in rs.error_rate:
         assert all(np.isfinite(v) for v in rs.error_rate[lab])
+
+
+# --------------------------------------------------------------------------
+# Degradation gate: heavy dropout, with and without bounded retry
+# --------------------------------------------------------------------------
+
+_DEGRADE_STRATS = (Strategy("sign", wire="packed"),
+                   Strategy("persymbol", rate=4), Strategy("original"))
+
+
+@pytest.fixture(scope="module")
+def dropout_ladder():
+    """The same d=16, 4-machine sweep lossless, at 25% dropout, and at 25%
+    dropout healed by 2 retries, each under the d2h transfer guard."""
+    def sweep(faults):
+        plan = TrialPlan(d=16, ns=(128, 512), strategies=_DEGRADE_STRATS,
+                         reps=32, seed0=7, faults=faults)
+        with jax.transfer_guard_device_to_host("disallow"):
+            return run_trials(plan)
+
+    return (sweep(None),
+            sweep(FaultPlan(dropout=0.25, machines=4, seed=1)),
+            sweep(FaultPlan(dropout=0.25, retries=2, machines=4, seed=1)))
+
+
+@pytest.mark.parametrize("claim", ["degradation_bounded",
+                                   "no_collapse_without_retry",
+                                   "one_sync_per_sweep"])
+def test_dropout_degrades_gracefully(dropout_ladder, claim):
+    """25% dropout healed by 2 retries stays within 0.15 of the lossless
+    error at the largest n; without retry the error stays off the
+    ceiling; the fault path keeps one host sync per sweep."""
+    lossless, dropped, retried = dropout_ladder
+    labels = [s.label for s in _DEGRADE_STRATS]
+    if claim == "degradation_bounded":
+        for lab in labels:
+            assert (retried.error_rate[lab][-1]
+                    <= lossless.error_rate[lab][-1] + 0.15), lab
+    elif claim == "no_collapse_without_retry":
+        assert all(dropped.error_rate[lab][-1] < 1.0 for lab in labels)
+    else:
+        assert [r.host_syncs for r in dropout_ladder] == [1, 1, 1]

@@ -217,6 +217,18 @@ def test_engine_backends_agree_sign(n, d):
             np.asarray(eng.packed_sign_gram(packed, n)), want)
 
 
+@pytest.mark.parametrize("eng", [PALLAS, XLA, NUMPY],
+                         ids=["pallas", "xla", "numpy"])
+def test_value_int8_and_packed_paths_agree_on_signs(eng):
+    """The f32-value, int8 and packed-bit Grams of one ±1 matrix are the
+    same integers on every backend."""
+    n, d = 1000, 40
+    u = _signs(n, d, seed=11)
+    g32 = np.asarray(eng.gram(jnp.asarray(u, jnp.float32)))
+    assert np.array_equal(g32, np.asarray(eng.gram(jnp.asarray(u))))
+    assert np.array_equal(g32, np.asarray(eng.packed_sign_gram(_pack(u), n)))
+
+
 def test_engine_backends_agree_codes():
     q = PerSymbolQuantizer(4)
     x = jax.random.normal(jax.random.key(0), (150, 21))

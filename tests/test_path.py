@@ -464,3 +464,103 @@ def test_sparse_wire_trial_plane_path_parity():
         assert r24.path['edge_f1'][lab] == ref.path['edge_f1'][lab]
         print('sparse path trial plane parity OK')
     """)
+
+
+# ---------------------------------------------------------------------------
+# early exit against the full-budget oracle on a seeded problem batch
+# ---------------------------------------------------------------------------
+
+_ORACLE_D, _ORACLE_N, _ORACLE_STEPS = 16, 400, 300
+_ORACLE_LAMS = (0.40, 0.28, 0.20, 0.141, 0.099, 0.070, 0.049, 0.035)
+
+
+def _oracle_problems(b: int = 16):
+    """b seeded small-sample problems (EBIC picks interior lams there):
+    (corr stack, true adjacency stack)."""
+    Ss, adjs = [], []
+    for i in range(b):
+        rng = np.random.default_rng(100 + i)
+        theta = glasso.random_sparse_precision(
+            _ORACLE_D, density=0.2, rng=rng)
+        x = np.asarray(sampler.sample_ggm(
+            jax.random.key(100 + i), _ORACLE_N, np.linalg.inv(theta)))
+        Ss.append(np.corrcoef(x, rowvar=False).astype(np.float32))
+        adj = np.abs(theta) > 1e-8
+        np.fill_diagonal(adj, False)
+        adjs.append(adj)
+    return jnp.asarray(np.stack(Ss)), np.stack(adjs)
+
+
+def _fused_select(conv_tol: float):
+    """One jitted launch: warm-started grid scan + on-device EBIC pick."""
+    @jax.jit
+    def run(S):
+        solve = glasso_path_batch(
+            S, jnp.asarray(_ORACLE_LAMS, jnp.float32),
+            n_steps=_ORACLE_STEPS, conv_tol=conv_tol,
+            support_tol=glasso.SUPPORT_TOL)
+        idx = select_ebic(ebic_scores(
+            solve.logdet, solve.tr_s_theta, solve.edges, _ORACLE_N,
+            _ORACLE_D, PathPlan().ebic_gamma))
+        sel = jnp.take_along_axis(
+            solve.support, idx[None, :, None, None], axis=0)[0]
+        return sel, idx, solve.iters
+
+    return run
+
+
+def _host_ebic_select(S):
+    """One cold full-budget glasso launch per lam, EBIC picked on the host
+    in float64."""
+    Sh = np.asarray(S, np.float64)
+    sups, scores = [], []
+    for lam in _ORACLE_LAMS:
+        th = np.asarray(glasso.glasso_batch(S, lam, n_steps=_ORACLE_STEPS),
+                        np.float64)
+        sup = np.asarray(glasso.support_from_theta(
+            jnp.asarray(th), glasso.SUPPORT_TOL))
+        e = sup.sum(axis=(-2, -1)) // 2
+        _, logdet = np.linalg.slogdet(th)
+        tr = (Sh * th).sum(axis=(-2, -1))
+        scores.append(-_ORACLE_N * (logdet - tr) + e * (
+            np.log(_ORACLE_N) + 2.0 * np.log(_ORACLE_D)))
+        sups.append(sup)
+    idx = np.argmin(np.stack(scores), axis=0)
+    return np.stack(sups)[idx, np.arange(S.shape[0])]
+
+
+def _mean_f1(est, true) -> float:
+    tp = (est & true).sum(axis=(-2, -1))
+    denom = est.sum(axis=(-2, -1)) + true.sum(axis=(-2, -1))
+    return float(np.mean(2.0 * tp / np.maximum(denom, 1)))
+
+
+@pytest.fixture(scope="module")
+def oracle_sweeps():
+    S, true_adj = _oracle_problems()
+    fused = _fused_select(PathPlan().conv_tol)
+    jax.block_until_ready(fused(S))  # compile outside the guard
+    with jax.transfer_guard_device_to_host("disallow"):
+        out = jax.block_until_ready(fused(S))
+    return (S, true_adj, jax.device_get(out),
+            jax.device_get(_fused_select(0.0)(S)))
+
+
+@pytest.mark.parametrize("claim", ["selection_matches_oracle_support",
+                                   "f1_not_worse_than_host_ebic",
+                                   "early_exit_saves_iterations"])
+def test_path_early_exit_against_oracle(oracle_sweeps, claim):
+    """At the default conv_tol the fused sweep (run once under the d2h
+    guard) selects exactly the full-budget oracle's supports on the
+    seeded batch, scores no worse than per-lam cold solves with a host
+    EBIC pick, and spends under half the cold iteration budget."""
+    S, true_adj, (sel, idx, iters), (o_sel, o_idx, _) = oracle_sweeps
+    if claim == "selection_matches_oracle_support":
+        assert (sel == o_sel).all() and (idx == o_idx).all()
+    elif claim == "f1_not_worse_than_host_ebic":
+        host = _host_ebic_select(S)
+        assert (_mean_f1(sel.astype(bool), true_adj)
+                >= _mean_f1(host.astype(bool), true_adj) - 1e-6)
+    else:
+        per_lam = iters.astype(np.float64).mean(axis=1)
+        assert per_lam.sum() < 0.5 * len(_ORACLE_LAMS) * _ORACLE_STEPS

@@ -623,6 +623,20 @@ sys.exit(3)
 """
 
 
+def _crash_child(tmp_path, crash_after: int) -> str:
+    """Run the trace in a child that SIGKILLs itself after ``crash_after``
+    journal records; returns the server directory it left behind."""
+    crash_dir = str(tmp_path / "crash")
+    script = tmp_path / "child.py"
+    script.write_text(_CHILD.format(
+        src=SRC, here=os.path.dirname(os.path.abspath(__file__)),
+        crash=crash_after))
+    r = subprocess.run([sys.executable, str(script), crash_dir],
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == -9, (r.returncode, r.stdout, r.stderr)
+    return crash_dir
+
+
 @pytest.mark.parametrize("crash_after", [17, 55])
 def test_crash_recovery_bit_identity(tmp_path, crash_after):
     """THE acceptance gate: SIGKILL mid-tick (between journal append and
@@ -633,14 +647,7 @@ def test_crash_recovery_bit_identity(tmp_path, crash_after):
     clean = _run_trace(
         StructureServer(ServeConfig(**_SCFG), str(tmp_path / "clean")),
         trace)
-    crash_dir = str(tmp_path / "crash")
-    script = tmp_path / "child.py"
-    script.write_text(_CHILD.format(
-        src=SRC, here=os.path.dirname(os.path.abspath(__file__)),
-        crash=crash_after))
-    r = subprocess.run([sys.executable, str(script), crash_dir],
-                       capture_output=True, text=True, timeout=600)
-    assert r.returncode == -9, (r.returncode, r.stdout, r.stderr)
+    crash_dir = _crash_child(tmp_path, crash_after)
 
     srv = StructureServer(ServeConfig(**_SCFG), crash_dir)  # replays WAL
     assert srv.recovered_records > 0 or srv.snapshot_step > 0
@@ -649,3 +656,16 @@ def test_crash_recovery_bit_identity(tmp_path, crash_after):
     for k in sc:
         assert np.array_equal(sc[k], sr[k]), f"{k} diverged after crash"
     clean.close(), srv.close()
+
+
+@pytest.mark.parametrize("crash_after", [17, 55])
+def test_crash_restart_replays_journal_and_drains(tmp_path, crash_after):
+    """The records journaled after the last snapshot are replayed on
+    restart (none is lost with the killed process), and once producers
+    re-send everything unacked no payload is left in a reorder buffer."""
+    srv = StructureServer(ServeConfig(**_SCFG),
+                          _crash_child(tmp_path, crash_after))
+    assert srv.recovered_records > 0
+    _run_trace(srv, make_trace(_TCFG))
+    assert srv.log.buffered() == 0
+    srv.close()

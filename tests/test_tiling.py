@@ -1,5 +1,5 @@
 """Large-d engine: (d_tile, d_tile) output streaming, n-chunked
-accumulation, pad-target selection, the autotune cache, and the
+accumulation, pad-target selection, the working-set model, and the
 memory-budgeted trial plane.
 
 Integer-exact paths (int8 signs, packed bits) must be BIT-identical under
@@ -8,7 +8,6 @@ values, centroid decode) are d-tiled only, so tiles change no per-entry
 reduction order; they are still compared allclose out of float caution.
 """
 import dataclasses
-import os
 
 import numpy as np
 import jax
@@ -16,8 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import gram as gram_mod
-from repro.core.gram import (GramConfig, GramEngine, candidate_configs,
-                             clear_autotune_cache, gram_working_set_bytes)
+from repro.core.gram import GramConfig, GramEngine, gram_working_set_bytes
 from repro.core.chow_liu import boruvka_mst_batch
 from repro.core.experiments import Strategy, TrialPlan, run_trials
 from repro.core.glasso import glasso_batch
@@ -151,102 +149,8 @@ def test_small_d_pad_bit_identity(n, d):
 
 
 # ---------------------------------------------------------------------------
-# autotune cache round-trip
+# memory budget
 # ---------------------------------------------------------------------------
-
-def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
-    cache = tmp_path / "gram_autotune.json"
-    monkeypatch.setenv(gram_mod.AUTOTUNE_CACHE_ENV, str(cache))
-    monkeypatch.delenv(gram_mod.AUTOTUNE_ENV, raising=False)
-    clear_autotune_cache()
-    eng = GramEngine(backend="xla", autotune=True)
-    try:
-        c0 = gram_mod.autotune_sweep_count()
-        win = eng.tune("int8", 64, 48)
-        assert gram_mod.autotune_sweep_count() == c0 + 1
-        assert cache.exists()
-        # in-memory hit: no new sweep
-        again = eng.tune("int8", 64, 48)
-        assert again == win
-        assert gram_mod.autotune_sweep_count() == c0 + 1
-        # drop memory, keep the file: reload, still no new sweep
-        clear_autotune_cache()
-        reloaded = eng.tune("int8", 64, 48)
-        assert reloaded == win
-        assert gram_mod.autotune_sweep_count() == c0 + 1
-        # same pow2 bucket -> same entry, different bucket -> new sweep
-        assert eng.tune("int8", 63, 47) == win
-        assert gram_mod.autotune_sweep_count() == c0 + 1
-    finally:
-        clear_autotune_cache()
-
-
-def test_autotune_disabled_env(monkeypatch):
-    monkeypatch.setenv(gram_mod.AUTOTUNE_ENV, "0")
-    clear_autotune_cache()
-    eng = GramEngine(backend="xla", autotune=True, d_tile=32)
-    c0 = gram_mod.autotune_sweep_count()
-    cfg = eng.tune("int8", 64, 48)
-    assert gram_mod.autotune_sweep_count() == c0  # hatch closed: no sweep
-    assert cfg.d_tile == 32  # engine's own config passes through
-
-
-def test_autotune_never_sweeps_under_trace(tmp_path, monkeypatch):
-    monkeypatch.setenv(gram_mod.AUTOTUNE_CACHE_ENV,
-                       str(tmp_path / "none.json"))
-    monkeypatch.delenv(gram_mod.AUTOTUNE_ENV, raising=False)
-    clear_autotune_cache()
-    eng = GramEngine(backend="xla", autotune=True)
-    u = jnp.asarray(_signs(64, 48, seed=1))
-    try:
-        c0 = gram_mod.autotune_sweep_count()
-        got = jax.jit(eng.gram)(u)
-        assert gram_mod.autotune_sweep_count() == c0
-        assert np.array_equal(np.asarray(got), np.asarray(XLA.gram(u)))
-    finally:
-        clear_autotune_cache()
-
-
-def test_autotune_reports_failed_candidates(tmp_path, monkeypatch, capsys):
-    """A candidate that raises is named on stderr and skipped; the sweep
-    still picks a winner from the rest, and the key names the device."""
-    monkeypatch.setenv(gram_mod.AUTOTUNE_CACHE_ENV, str(tmp_path / "c.json"))
-    monkeypatch.delenv(gram_mod.AUTOTUNE_ENV, raising=False)
-    real = gram_mod._time_config
-
-    def flaky(engine, cfg, *a):
-        if cfg.d_tile is not None:
-            raise ValueError("refused by the compiler")
-        return real(engine, cfg, *a)
-
-    monkeypatch.setattr(gram_mod, "_time_config", flaky)
-    clear_autotune_cache()
-    try:
-        win = GramEngine(backend="xla", autotune=True).tune("int8", 64, 300)
-        assert win.d_tile is None
-        err = capsys.readouterr().err
-        assert "skipped" in err and "refused by the compiler" in err
-        key = gram_mod._tune_key("int8", 64, 300, "xla")
-        assert jax.devices()[0].device_kind in key
-    finally:
-        clear_autotune_cache()
-
-
-def test_autotune_raises_when_every_candidate_fails(tmp_path, monkeypatch):
-    monkeypatch.setenv(gram_mod.AUTOTUNE_CACHE_ENV, str(tmp_path / "c.json"))
-    monkeypatch.delenv(gram_mod.AUTOTUNE_ENV, raising=False)
-
-    def broken(*a):
-        raise ValueError("refused by the compiler")
-
-    monkeypatch.setattr(gram_mod, "_time_config", broken)
-    clear_autotune_cache()
-    try:
-        with pytest.raises(RuntimeError, match="every candidate failed"):
-            GramEngine(backend="xla", autotune=True).tune("int8", 64, 48)
-    finally:
-        clear_autotune_cache()
-
 
 def test_memory_budget_needs_a_tpu_limit(monkeypatch):
     """A TPU that reports no bytes_limit is an error, not an assumed 8 GiB;
@@ -270,15 +174,42 @@ def test_memory_budget_needs_a_tpu_limit(monkeypatch):
     assert gram_mod.default_memory_budget() == 8 << 30
 
 
-def test_candidate_configs_respect_budget():
-    n, d = 8192, 4096
-    budget = 96 << 20
-    assert gram_working_set_bytes("packed", n, d, backend="xla") > budget
-    cands = candidate_configs("packed", n, d, "xla", budget=budget)
-    assert cands  # something always survives
-    for cfg in cands:
-        assert gram_working_set_bytes(
-            "packed", n, d, backend="xla", config=cfg) <= budget
+# d=4096 at n=8192 under a 96 MiB budget: the monolithic xla packed path
+# stages an unpack plane over it; tiles fit it, and the plan finds them.
+_N4K, _D4K, _BUDGET_4K = 8192, 4096, 96 << 20
+
+
+def _packed_ws_4k(config=None, backend="xla") -> int:
+    return gram_working_set_bytes("packed", _N4K, _D4K, backend=backend,
+                                  config=config)
+
+
+def _budget_config_4k() -> GramConfig:
+    """The tiling the plan's budget engine picks (it gives the Gram half
+    of the plan's budget)."""
+    plan = TrialPlan(d=_D4K, ns=(_N4K,),
+                     strategies=(Strategy("sign", wire="packed"),), reps=1,
+                     memory_budget_bytes=2 * _BUDGET_4K)
+    eng = plan.budget_engine(XLA)
+    return GramConfig(d_tile=eng.d_tile, n_chunk=eng.n_chunk)
+
+
+_LARGE_D_CLAIMS = {
+    "monolithic_exceeds_budget": lambda: _packed_ws_4k() > _BUDGET_4K,
+    "tiled_within_budget": lambda: _packed_ws_4k(
+        GramConfig(d_tile=1024, n_chunk=8192)) <= _BUDGET_4K,
+    "budget_engine_within_budget": lambda: _packed_ws_4k(
+        _budget_config_4k()) <= _BUDGET_4K,
+    # the packed operand is at least 16x lighter than the f32 one
+    "packed_operand_leq_16th_f32": lambda: 16 * _packed_ws_4k(
+        backend="pallas") <= gram_working_set_bytes(
+            "f32", _N4K, _D4K, backend="pallas"),
+}
+
+
+@pytest.mark.parametrize("claim", sorted(_LARGE_D_CLAIMS))
+def test_large_d_budget_claims(claim):
+    assert _LARGE_D_CLAIMS[claim]()
 
 
 # ---------------------------------------------------------------------------
