@@ -20,9 +20,10 @@ any input.
 
 Device vs host flow: with ``backend="boruvka"`` the weight matrix feeds
 ``boruvka_mst`` directly as a JAX array and the result is the bool
-adjacency — nothing bounces through numpy. Converting an adjacency to the
-human-facing edge list (:func:`adjacency_to_edges`) is an explicit host
-step, taken only at the edge-list API surface.
+adjacency — nothing bounces through numpy — or, where only the edge list
+is wanted, the solve's compact edge record (``edges=True``). Converting
+either to the human-facing edge list (:func:`adjacency_to_edges`) is an
+explicit host step, taken only at the edge-list API surface.
 """
 from __future__ import annotations
 
@@ -123,14 +124,21 @@ def _edge_order(weights: jax.Array) -> tuple[jax.Array, jax.Array]:
     return jnp.where(own, key, key.T), jnp.where(own, tie, tie.T)
 
 
-@jax.jit
-def boruvka_mst(weights: jax.Array) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("edges",))
+def boruvka_mst(weights: jax.Array, edges: bool = False) -> jax.Array:
     """Max-weight spanning tree via parallel Boruvka.
 
     Args:
       weights: symmetric (d, d) edge-weight matrix (diagonal ignored).
+      edges: static; what the solve hands back (see Returns).
     Returns:
-      (d, d) bool adjacency of the MWST (symmetric).
+      ``edges=False``: the (d, d) bool adjacency of the MWST (symmetric).
+      ``edges=True``: the (R, d) int32 record of the edges the solve chose,
+      R = ceil(log2 d): row r holds round r's champion of each node's
+      component as a flat tie index ``j * d + k`` of either orientation,
+      ``int32.max`` where no round ran. Every tree edge appears in it, and
+      nothing else; :func:`adjacency_to_edges` reads it in O(R d) on the
+      host where the adjacency costs O(d^2).
 
     Edges are ordered by :func:`_edge_order`, a strict total order, so the
     tree is unique and equals :func:`kruskal_mst`'s. Each round every
@@ -139,7 +147,8 @@ def boruvka_mst(weights: jax.Array) -> jax.Array:
     few O(d^2) passes and lowers to no scatter, gather or sort: on a TPU
     those run element by element. A component's label is one of its
     nodes, so a label indexes per-node vectors through the one-hot
-    reduction ``take``.
+    reduction ``take``. Every component merges each round, so their count
+    at least halves and R rounds suffice.
 
     The round body is idempotent once a single component remains, so the
     while_loop batches correctly under ``vmap`` (trials that converge
@@ -159,8 +168,7 @@ def boruvka_mst(weights: jax.Array) -> jax.Array:
         g = take(f, f)
         return g, jnp.any(g != f)
 
-    def round_body(state):
-        comp, chosen, _ = state
+    def champion(comp):
         same = comp[:, None] == comp
         # node j's best edge out of its component, then the component's
         # best over its members: the champion, named by its tie
@@ -169,8 +177,9 @@ def boruvka_mst(weights: jax.Array) -> jax.Array:
         champ_key = jnp.where(same, best_key[:, None], no_key).max(0)
         champ = jnp.where(same & (best_key[:, None] == champ_key),
                           best_tie[:, None], no_tie).min(0)
-        # T[k, j] == champ[j] only at the champion's ends, j inside
-        chosen = chosen | (T == champ)
+        return same, champ
+
+    def merge(comp, same, champ):
         ends = (node[:, None] == champ // d) | (node[:, None] == champ % d)
         across = jnp.where(ends & ~same, comp[:, None], no_tie).min(0)
         hook = jnp.where(champ < no_tie, across, comp)
@@ -180,7 +189,28 @@ def boruvka_mst(weights: jax.Array) -> jax.Array:
         f = jnp.where(mutual & (comp < hook), comp, hook)
         comp, _ = jax.lax.while_loop(lambda s: s[1], jump,
                                      (f, jnp.asarray(True)))
-        return comp, chosen, jnp.sum(comp == node)
+        return comp, jnp.sum(comp == node)
+
+    if edges:
+        def record_round(state):
+            comp, record, _, r = state
+            same, champ = champion(comp)
+            record = jax.lax.dynamic_update_slice(record, champ[None], (r, 0))
+            comp, count = merge(comp, same, champ)
+            return comp, record, count, r + 1
+
+        rounds = max((d - 1).bit_length(), 1)   # ceil(log2 d)
+        init = (node, jnp.full((rounds, d), no_tie, jnp.int32),
+                jnp.asarray(d, jnp.int32), jnp.asarray(0, jnp.int32))
+        return jax.lax.while_loop(lambda s: s[2] > 1, record_round, init)[1]
+
+    def round_body(state):
+        comp, chosen, _ = state
+        same, champ = champion(comp)
+        # T[k, j] == champ[j] only at the champion's ends, j inside
+        chosen = chosen | (T == champ)
+        comp, count = merge(comp, same, champ)
+        return comp, chosen, count
 
     init = (node, jnp.zeros((d, d), dtype=bool), jnp.asarray(d, jnp.int32))
     _, chosen, _ = jax.lax.while_loop(lambda s: s[2] > 1, round_body, init)
@@ -215,7 +245,14 @@ def boruvka_mst_batch(weights: jax.Array, chunk: int | None = None
 
 
 def adjacency_to_edges(adj) -> list[tuple[int, int]]:
-    """Explicit host step: symmetric bool adjacency -> canonical edge list.
+    """Explicit host step: a structure -> canonical edge list, the (j, k),
+    j < k, edges in row-major order of the upper triangle.
+
+    ``adj`` is either a symmetric (d, d) bool adjacency (a glasso support,
+    ``StreamingGram``) or the int32 edge record of
+    ``boruvka_mst(w, edges=True)``, whose O(d log d) entries are read back
+    and deduplicated instead of a (d, d) scan; both forms of one tree give
+    the same list.
 
     The read-back (which waits for the device) is one
     ``repro.structure.fetch`` span, the host extraction one
@@ -223,8 +260,15 @@ def adjacency_to_edges(adj) -> list[tuple[int, int]]:
     with span("structure.fetch"):
         adj = np.asarray(adj)
     with span("structure.edges"):
-        iu, ju = np.nonzero(np.triu(adj, k=1))
-        return [(int(a), int(b)) for a, b in zip(iu, ju)]
+        if adj.dtype == bool:
+            iu, ju = np.nonzero(np.triu(adj, k=1))
+        else:
+            d = adj.shape[-1]
+            ties = adj[adj != np.iinfo(np.int32).max]
+            j, k = np.divmod(ties, d)
+            iu, ju = np.divmod(np.unique(np.minimum(j, k) * d
+                                         + np.maximum(j, k)), d)
+        return list(zip(iu.tolist(), ju.tolist()))
 
 
 # --------------------------------------------------------------------------
@@ -238,7 +282,8 @@ def chow_liu(weights, backend: str = "kruskal") -> list[tuple[int, int]]:
     elif backend == "boruvka":
         # device solve on the weights as-is; host conversion only at the
         # edge-list API surface
-        return adjacency_to_edges(boruvka_mst(jnp.asarray(weights)))
+        return adjacency_to_edges(boruvka_mst(jnp.asarray(weights),
+                                              edges=True))
     raise ValueError(f"unknown backend {backend!r}")
 
 
@@ -283,7 +328,9 @@ def learn_structure(
       (sign) / int8 bin codes with in-kernel centroid decode (persymbol).
 
     With ``backend='boruvka'`` (``strategy.mst``) the weights feed the
-    device solver directly; only the final edge list crosses to the host.
+    device solver directly, and only the solve's edge record
+    (``boruvka_mst(w, edges=True)``: O(d log d) int32, not the (d, d)
+    adjacency) crosses to the host.
 
     Each call is one ``repro.learn_structure`` span; the MWST solve (its
     dispatch, for Boruvka) is one ``repro.structure.mst`` span inside it.
@@ -299,7 +346,7 @@ def learn_structure(
     w = estimators.strategy_weights(x, strategy, engine=engine)
     if strategy.mst == "boruvka":
         with span("structure.mst"):
-            adj = boruvka_mst(w)
-        return adjacency_to_edges(adj)
+            record = boruvka_mst(w, edges=True)
+        return adjacency_to_edges(record)
     with span("structure.mst"):
         return kruskal_mst(np.asarray(w))

@@ -675,7 +675,9 @@ def distributed_learn_structure(
     the EBIC-SELECTED structure — no caller-chosen penalty needed.
 
     The MWST solver comes from ``backend`` if given, else
-    ``strategy.mst``, else the on-device Boruvka default.
+    ``strategy.mst``, else the on-device Boruvka default, which hands the
+    host its O(d log d) edge record (``boruvka_mst(w, edges=True)``), not
+    the (d, d) adjacency.
 
     Each call is one ``repro.distributed_learn_structure`` span; a tree
     strategy's MWST solve (its dispatch, for Boruvka) is one
@@ -699,10 +701,10 @@ def distributed_learn_structure(
     if backend is None:
         backend = strategy.mst if strategy is not None else "boruvka"
     if backend == "boruvka":
-        # device solve on the replicated weights; host conversion only at
-        # the edge-list surface
+        # device solve on the replicated weights; only its edge record
+        # crosses to the host, at the edge-list surface
         with span("structure.mst"):
-            adj = boruvka_mst(w)
-        return adjacency_to_edges(adj)
+            record = boruvka_mst(w, edges=True)
+        return adjacency_to_edges(record)
     with span("structure.mst"):
         return kruskal_mst(np.asarray(w))

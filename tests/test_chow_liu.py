@@ -141,11 +141,67 @@ def test_boruvka_batches_bit_identical_to_rank_reference(solve):
     np.testing.assert_array_equal(np.asarray(got), ref)
 
 
+def _kruskal_on_edge_order(w):
+    """Reference for any (d, d) weights, asymmetric and non-finite ones
+    included: ``kruskal_mst`` on distinct ranks of the order the device
+    solve uses (edge {j,k} takes the larger orientation, NaN below -inf,
+    ties to the smaller flat index), as (j, k), j < k, edges in row-major
+    order. On symmetric finite weights it is ``kruskal_mst(w)`` sorted."""
+    w = np.asarray(w, np.float64)
+    d = len(w)
+    real = ~np.isnan(w)
+    v = np.where(real, w, -np.inf)
+    flat = np.arange(d * d).reshape(d, d)
+    own = (real & ~real.T) | ((real == real.T) & (
+        (v > v.T) | ((v == v.T) & (flat < flat.T))))
+    real, v, tie = (np.where(own, a, a.T) for a in (real, v, flat))
+    iu, ju = np.triu_indices(d, 1)
+    order = np.lexsort((tie[iu, ju], -v[iu, ju], ~real[iu, ju]))
+    ranks = np.zeros((d, d))
+    ranks[iu[order], ju[order]] = np.arange(len(order), 0, -1)
+    return sorted(CL.kruskal_mst(ranks + ranks.T))
+
+
+@pytest.mark.parametrize("family", _FAMILIES)
+@pytest.mark.parametrize("d", [2, 3, 5, 8, 20, 33, 130, 257, 1024])
+def test_boruvka_edge_record_gives_the_adjacency_edges(d, family):
+    """The compact result of the same solve: the same edge list, in the
+    same order, as the adjacency's, and Kruskal's tree, from a record of
+    at most ceil(log2 d) rounds."""
+    w = _family_weights(family, (d, d), seed=7 * d + len(family))
+    record = CL.boruvka_mst(w, edges=True)
+    got = CL.adjacency_to_edges(record)
+    assert got == CL.adjacency_to_edges(CL.boruvka_mst(w))
+    assert got == _kruskal_on_edge_order(w)
+    assert len(got) == d - 1
+    assert record.dtype == jnp.int32
+    assert record.shape == (int(np.ceil(np.log2(d))), d)
+
+
+@pytest.mark.parametrize("solve", ["single", "vmap", "batch",
+                                   "batch_chunk7"])
+def test_boruvka_default_returns_bool_adjacency(solve):
+    """Callers that read the adjacency (the sweep's metrics, serving,
+    ``StreamingGram``) get the (d, d) bool by default."""
+    W = _family_weights("normal", (9, 12, 12), seed=3)
+    if solve == "single":
+        got, want = CL.boruvka_mst(W[0]), (12, 12)
+    elif solve == "vmap":
+        got, want = jax.vmap(CL.boruvka_mst)(W), (9, 12, 12)
+    elif solve == "batch":
+        got, want = CL.boruvka_mst_batch(W), (9, 12, 12)
+    else:
+        got, want = CL.boruvka_mst_batch(W, chunk=7), (9, 12, 12)
+    assert got.dtype == jnp.bool_ and got.shape == want
+    np.testing.assert_array_equal(np.asarray(got), np.swapaxes(got, -1, -2))
+
+
 @pytest.mark.parametrize("fn,shape", [
     (CL.boruvka_mst, (1024, 1024)),
     (CL.boruvka_mst_batch, (6000, 20, 20)),
     (functools.partial(CL.boruvka_mst_batch, chunk=1000), (6000, 20, 20)),
-], ids=["single_d1024", "batch", "batch_chunk1000"])
+    (functools.partial(CL.boruvka_mst, edges=True), (1024, 1024)),
+], ids=["single_d1024", "batch", "batch_chunk1000", "single_d1024_edges"])
 def test_boruvka_lowers_without_scatter_gather_sort(fn, shape):
     """Data-dependent indexed updates and reads run element by element on
     a TPU; the solver is built of dense compares and reductions only."""

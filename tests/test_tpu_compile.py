@@ -14,6 +14,7 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process may hold the TPU library, and every test worker imports
 this file.
 """
+import functools
 import os
 import re
 
@@ -103,10 +104,12 @@ def test_sign_corr_packed_compiles_for_v5e(one_chip, shape, n):
     (boruvka_mst, (1024, 1024)),
     (jax.vmap(boruvka_mst), (6000, 20, 20)),
     (boruvka_mst_batch, (6000, 20, 20)),
-], ids=["single_d1024", "vmap", "batch"])
+    (functools.partial(boruvka_mst, edges=True), (1024, 1024)),
+], ids=["single_d1024", "vmap", "batch", "single_d1024_edges"])
 def test_boruvka_compiles_for_v5e(one_chip, fn, shape):
-    """The structure cell's d=1024 solve and the sweep's stack of 6000
-    d=20 trials, vmapped and laid along lanes."""
+    """The structure cells' d=1024 solve, as adjacency and as the edge
+    record they read back, and the sweep's stack of 6000 d=20 trials,
+    vmapped and laid along lanes."""
     text = _compile_text(fn, one_chip, (shape, jnp.float32))
     ops = set(re.findall(r"\s([a-z][\w-]*)\(%", text))
     assert "while" in ops
